@@ -15,6 +15,8 @@ import json
 import os
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 BENCHMARKS = [
     ("fig3", "benchmarks.fig3_failure_model", {}),
     ("fig6", "benchmarks.fig6_freq_update_corr", {}),
@@ -52,6 +54,7 @@ def main() -> None:
     ap.add_argument("--only", default="")
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     os.makedirs("artifacts/bench", exist_ok=True)

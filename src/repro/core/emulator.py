@@ -63,10 +63,14 @@ class Emulator:
         self.seed = seed
         self.eval_frac = eval_frac
         self.use_kernel = use_kernel
+        if use_kernel:
+            from repro.kernels import ops
+            ops.require_lowering("embedding_bag")
         # any Optimizer whose state carries row-wise accumulators under
         # state["acc"] (extra top-level entries — step counters, momenta —
         # are preserved across failure restores)
         self.optimizer = optimizer
+        self.final_params = None
         self.final_ostate = None
 
     def _build_step(self):
@@ -170,6 +174,7 @@ class Emulator:
                           "acc": {**ostate["acc"],
                                   "tables": [jnp.asarray(x) for x in new_a]}}
         mgr.close()   # drain + stop the async writer thread (if any)
+        self.final_params = params
         self.final_ostate = ostate
 
         # ---- evaluation ----

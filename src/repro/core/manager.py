@@ -90,6 +90,14 @@ class CPRManager:
         assert mode in ALL_MODES, mode
         assert tracker_backend in ("host", "pallas"), tracker_backend
         assert hash_backend in ("host", "pallas"), hash_backend
+        # a Pallas backend whose kernel the chip's compiler refuses fails
+        # here, not mid-run (and never falls back to the interpreter)
+        if tracker_backend == "pallas" and mode == "cpr-ssu":
+            from repro.kernels import ops
+            ops.require_lowering("ssu_dedupe")
+        if hash_backend == "pallas":
+            from repro.kernels import ops
+            ops.require_lowering("row_hash")
         self.mode = mode
         self.p = sys_params
         self.target_pls = target_pls
@@ -676,6 +684,7 @@ class CPRManager:
             "n_failures": self.n_failures,
             "overheads": self.ledger.as_dict(self.p.T_total),
             "bytes_written": self.store.bytes_written if self.store else 0,
+            "shard_failures": sorted(self.shard_failures),
             "decision": self.decision,
         }
         if self.sharded_save and self.store is not None:
@@ -684,9 +693,8 @@ class CPRManager:
             out["delta_rows_skipped"] = self.store.delta_rows_skipped
             out["delta_bytes_skipped"] = self.store.delta_bytes_skipped
             out["dropped_bytes"] = self.store.dropped_bytes
-            # shard_failures is the historical record; poisoned_shards the
-            # shards still out of the fleet (empty again after re-admission)
-            out["shard_failures"] = sorted(self.shard_failures)
+            # poisoned_shards: the shards still out of the fleet (empty
+            # again after re-admission); shard_failures is the history
             out["poisoned_shards"] = sorted(self.store.failed)
             out["shard_readmissions"] = self.store.shard_readmissions
             out["coordinator_epoch"] = self.store.epoch

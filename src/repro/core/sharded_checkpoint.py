@@ -513,6 +513,7 @@ class ShardedCheckpointWriter:
         self.hash_backend = hash_backend
         if hash_backend == "pallas":
             from repro.kernels import ops as _kops
+            _kops.require_lowering("row_hash")
             self._row_hash = _kops.row_hash
         else:
             self._row_hash = row_hash

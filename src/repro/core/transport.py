@@ -139,7 +139,8 @@ import numpy as np
 from repro.analysis.protocol.spec import MAX_FRAME_BYTES
 from repro.analysis.protocol.spec import violation as _spec_violation
 from repro.core.checkpoint import (AsyncApplier, EmbShardSpec, _leaves,
-                                   load_trainer_tree, save_trainer_tree)
+                                   _to_numpy, load_trainer_tree,
+                                   save_trainer_tree)
 
 # Default seconds the coordinator waits for a shard's DRAIN ack before
 # declaring the writer dead.  Generous: a healthy worker only has bounded
@@ -2126,7 +2127,7 @@ class RemoteEndpoint(ShardEndpoint):
                     spec.n_shards, [b.tolist() for b in spec.boundaries],
                     directory,
                     [np.asarray(t) for t in seed[0]],
-                    [np.asarray(a) for a in seed[1]], seed[2]))
+                    [np.asarray(a) for a in seed[1]], _to_numpy(seed[2])))
         msg = self._recv_until("resharded", timeout)
         if msg is None or self._exc is not None:
             raise WriterProcError(
@@ -2170,8 +2171,11 @@ class PipeEndpoint(RemoteEndpoint):
         import multiprocessing as mp
         ctx = mp.get_context("spawn")
         parent, child = ctx.Pipe()
+        # only numpy crosses into the child: a device-array leaf would
+        # start a JAX backend there on unpickling, contending with the
+        # trainer for the accelerator
         seed = ([np.asarray(t) for t in seed_tables],
-                [np.asarray(a) for a in seed_accs], trainer_image)
+                [np.asarray(a) for a in seed_accs], _to_numpy(trainer_image))
         self.proc = ctx.Process(
             target=_pipe_worker_main,
             args=(child, self.shard, self.spec, self.directory, seed,
@@ -2354,7 +2358,7 @@ class SocketEndpoint(RemoteEndpoint):
 
     def _spawn(self, seed_tables, seed_accs, trainer_image):
         seed = ([np.asarray(t) for t in seed_tables],
-                [np.asarray(a) for a in seed_accs], trainer_image)
+                [np.asarray(a) for a in seed_accs], _to_numpy(trainer_image))
         if self._mux is not None:
             # first spawn over a shared mux connection: the transport
             # already ran the hello (codec + shm negotiation) for the

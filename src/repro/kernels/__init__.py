@@ -1,11 +1,1 @@
 """Pallas-TPU kernels; see ops.py for the jit'd public wrappers."""
-from __future__ import annotations
-
-from jax.experimental.pallas import tpu as pltpu
-
-
-def compiler_params(**kw):
-    """Version-compat constructor: ``pltpu.CompilerParams`` (jax >= 0.6)
-    falls back to ``pltpu.TPUCompilerParams`` (jax 0.4.x)."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)

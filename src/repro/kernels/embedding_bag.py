@@ -8,6 +8,12 @@ the output block.
 
 Grid: (B, hot, d_blocks); output block (1, bd) revisited across the ``hot``
 dimension with accumulate-or-init (standard TPU reduction pattern).
+
+Interpret mode only: Mosaic refuses the (1, bd) row block (the last two
+block dims must be (8k, 128k) or the array's own).  Squeezing the row
+axis of an (N, 1, d) view does compile, but at d=16 it relays the table
+out with every row padded to 128 lanes (5.2 GB of temporaries for one
+10M-row Kaggle table), so ``ops.embedding_bag`` refuses to run on a TPU.
 """
 from __future__ import annotations
 
@@ -18,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
 
 
 def _kernel(idx_ref, table_ref, out_ref):
@@ -55,7 +60,7 @@ def embedding_bag(table, idx, block_d: int = 512, interpret: bool = True):
         ),
         out_shape=jax.ShapeDtypeStruct((B, d), table.dtype),
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary",
                                              "parallel")),
     )(idx, table)

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
 
 NEG_INF = -1e30
 
@@ -104,7 +103,7 @@ def flash_attention(q, k, v, causal=True, window=0, softcap=0.0,
             pltpu.VMEM((bq, hd), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel",
                                              "parallel", "arbitrary")),
     )(q, k, v)

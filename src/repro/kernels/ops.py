@@ -1,9 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute in ``interpret=True`` mode (the
-kernel body runs as traced JAX ops — bit-accurate semantics, no Mosaic);
-on TPU the same calls compile through Mosaic.  ``flash_attention`` adapts
-the model-layer layout (B, S, H, hd) to the kernel layout (B, H, S, hd).
+Off the TPU the kernels execute in ``interpret=True`` mode (the kernel
+body runs as traced JAX ops — bit-accurate semantics, no Mosaic); on a
+TPU the same calls compile through Mosaic.  ``_interpret()`` is the one
+switch.  Kernels Mosaic refuses (``NO_TPU_LOWERING``) raise on a TPU
+instead of falling back to the interpreter, and constructors call
+``require_lowering`` to refuse such a backend choice up front.
+``flash_attention`` adapts the model-layer layout (B, S, H, hd) to the
+kernel layout (B, H, S, hd).
 """
 from __future__ import annotations
 
@@ -17,11 +21,30 @@ from repro.kernels import ssu_dedupe as _sd
 from repro.kernels import tracker_select as _ts
 
 
+# kernel -> why the TPU's compiler refuses it
+NO_TPU_LOWERING = {
+    "embedding_bag": "its (1, d) row block breaks the TPU tiling rule",
+    "row_hash": "its FNV state needs uint64 lanes, which the TPU lacks",
+    "ssu_dedupe": "Pallas TPU cannot lower sort",
+}
+
+
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def require_lowering(kernel: str) -> None:
+    """Raise if ``kernel`` cannot run on this backend without the
+    interpreter (i.e. it is in ``NO_TPU_LOWERING`` and this is a TPU)."""
+    why = NO_TPU_LOWERING.get(kernel)
+    if why is not None and not _interpret():
+        raise NotImplementedError(
+            f"the Pallas {kernel} kernel cannot run on a TPU: {why}; "
+            f"choose the host/jnp path")
+
+
 def embedding_bag(table, idx, block_d: int = 512):
+    require_lowering("embedding_bag")
     return _eb.embedding_bag(table, idx, block_d=block_d,
                              interpret=_interpret())
 
@@ -55,14 +78,13 @@ def autotune_seg_size(n_rows: int, k: int, **kw) -> int:
 
 
 def row_hash(values, acc_values) -> "np.ndarray":
-    """FNV-1a per-row delta-save hash -> (n,) uint64 numpy array.
-
-    Always interpret mode: the 64-bit FNV state has no Mosaic lowering
-    yet (TPU int lanes are 32-bit; a limb split is the ROADMAP item)."""
-    return _rh.row_hash(values, acc_values, interpret=True)
+    """FNV-1a per-row delta-save hash -> (n,) uint64 numpy array."""
+    require_lowering("row_hash")
+    return _rh.row_hash(values, acc_values, interpret=_interpret())
 
 
 def ssu_dedupe_evict(buf, cand, scores):
     """Fused SSU reservoir dedupe + random-evict (sorted int32 buffer)."""
+    require_lowering("ssu_dedupe")
     return _sd.ssu_dedupe_evict(buf, cand, scores,
                                 interpret=_interpret())

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
 
 
 def _kernel(a_ref, b_ref, o_ref, h_ref, *, bs):
@@ -61,7 +60,7 @@ def rglru_scan(a, b, block_s: int = 256, block_w: int = 512,
         out_shape=jax.ShapeDtypeStruct((B, S, w), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel",
                                              "arbitrary")),
     )(a, b)

@@ -14,11 +14,11 @@ preprocessing the numpy implementation does, so the kernel is bit-exact
 against ``ref.row_hash`` and ``sharded_checkpoint.row_hash`` for every
 dtype and row width, including zero-row and zero-column slices.
 
-The kernel runs under a scoped ``jax.experimental.enable_x64()`` (uint64
-lanes; the global default stays 32-bit so nothing else in the process
-changes dtype).  ``interpret=True`` always on this container; a Mosaic
-lowering needs the 64-bit state split into 32-bit limbs (TPU has no
-64-bit int lanes) — tracked in ROADMAP item 4, the interpret path is the
+The kernel runs under a scoped ``jax.enable_x64(True)`` (uint64 lanes;
+the global default stays 32-bit so nothing else in the process changes
+dtype).  It runs in interpret mode only: a Mosaic lowering needs the
+64-bit state split into 32-bit limbs (TPU has no 64-bit int lanes), so
+``ops.row_hash`` refuses to run it on a TPU.  The interpret path is the
 bit-exactness contract any limb split must keep.
 """
 from __future__ import annotations
@@ -29,8 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
 from repro.kernels import ref as _ref
 
 FNV_OFFSET = np.uint64(14695981039346656037)
@@ -66,8 +66,7 @@ def row_hash(values, acc_values, block_rows: int = 1024,
     padded = n_blk * bn
     if padded != n:                       # padding rows hash and are cut
         w = np.pad(w, ((0, padded - n), (0, 0)))
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         out = pl.pallas_call(
             functools.partial(_fnv_kernel, m=m),
             grid=(n_blk,),
@@ -75,7 +74,7 @@ def row_hash(values, acc_values, block_rows: int = 1024,
             out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
             out_shape=jax.ShapeDtypeStruct((padded,), jnp.uint64),
             interpret=interpret,
-            compiler_params=compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
         )(jnp.asarray(w))
         # np.array, not asarray: the zero-copy view of the device buffer

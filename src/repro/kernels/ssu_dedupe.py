@@ -12,10 +12,10 @@ argument, so the randomness stream is identical between the host and
 kernel backends and results match bit for bit (``ref.ssu_dedupe_evict``
 is the exact-match oracle, stable argsort on both sides).
 
-Single-block kernel (the reservoir is r·N ids — thousands, not
-millions); ``interpret=True`` on this CPU container, and the body is
-jnp sort/argsort primitives so the Mosaic path is gated on TPU sort
-support rather than a rewrite.
+Single-block kernel over jnp sort/argsort primitives.  Pallas has no TPU
+lowering for sort, so the kernel runs in interpret mode only and
+``ops.ssu_dedupe_evict`` refuses to run it on a TPU; there the ``host``
+tracker backend (XLA's own sort inside the train step) is the path.
 """
 from __future__ import annotations
 

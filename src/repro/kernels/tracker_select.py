@@ -11,16 +11,19 @@ distributions hot rows are spread across segments, so segment-wise
 selection covers the same hot set while needing only an O(seg) scan per
 grid step — no global argsort, no host round-trip.
 
-Grid: one step per segment.  Each step
+Grid: one step per segment, over counters laid out (n_seg, 1, seg) with
+the segment axis squeezed out of the block, so every block's last two
+dims equal the array's (Mosaic's tiling rule).  Each step
   1. DMAs its (1, seg) counter block into VMEM,
-  2. adds the pending-id histogram for its row range (computed by comparing
-     the prefetched flat id list against the segment's global row iota),
+  2. adds the pending-id histogram for its row range, comparing the
+     pending id list against the segment's global row iota ``chunk`` ids
+     at a time (a fixed-size VMEM compare buffer at any pending count),
   3. runs ``k`` max/argmin-of-tie iterations to emit the segment's top-k
      global row ids,
   4. writes back the updated counters with the selected rows cleared.
 
-``interpret=True`` (the CPU container) runs the same kernel body as traced
-JAX ops — bit-identical to the Mosaic path and to ``ref.tracker_select``.
+``interpret=True`` (off the TPU) runs the same kernel body as traced JAX
+ops — bit-identical to the Mosaic path and to ``ref.tracker_select``.
 """
 from __future__ import annotations
 
@@ -31,8 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
 
 _INT32_MIN = jnp.iinfo(jnp.int32).min
 
@@ -43,32 +46,43 @@ _INT32_MIN = jnp.iinfo(jnp.int32).min
 # slips through CPU tests and breaks on hardware — hence the guard below.
 LANE_WIDTH = 128
 
+# pending ids folded per inner step: bounds the kernel's (chunk, seg)
+# compare buffer in VMEM (512 x 512 int32 = 1 MiB) at any pending count
+_PENDING_CHUNK = 512
 
-def _kernel(idx_ref, cnt_ref, out_idx_ref, out_cnt_ref, *, seg: int, k: int):
+
+def _kernel(idx_ref, cnt_ref, out_idx_ref, out_cnt_ref, *, seg: int, k: int,
+            chunk: int):
     lo = pl.program_id(0) * seg
     col = jax.lax.broadcasted_iota(jnp.int32, (1, seg), 1)        # 0..seg-1
     gid = lo + col                                                # global ids
-    # --- fused count update: histogram of pending ids over this segment ---
-    hits = jnp.sum((idx_ref[...] == gid).astype(jnp.int32), axis=0,
-                   keepdims=True)                                 # (1, seg)
-    counts = cnt_ref[...] + hits
+    # --- fused count update: histogram of pending ids over this segment,
+    # folded ``chunk`` ids at a time so the (chunk, seg) compare buffer
+    # stays a fixed VMEM size whatever the pending count ---
+    def fold(c, hits):
+        start = pl.multiple_of(c * chunk, chunk)
+        ids = idx_ref[pl.ds(start, chunk), :]                     # (chunk, 1)
+        return hits + jnp.sum((ids == gid).astype(jnp.int32), axis=0,
+                              keepdims=True)                      # (1, seg)
+    n_chunks = idx_ref.shape[0] // chunk
+    counts = cnt_ref[...] + jax.lax.fori_loop(
+        0, n_chunks, fold, jnp.zeros((1, seg), jnp.int32))
 
-    # --- segment-wise top-k (ties -> lowest row id) ---
+    # --- segment-wise top-k (ties -> lowest row id); a picked row's work
+    # entry drops to INT32_MIN, which no live or padding count reaches ---
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+
     def body(j, carry):
-        work, selected, ids = carry
-        m = jnp.max(work)
-        pos = jnp.min(jnp.where(work == m, col, seg))
-        ids = jax.lax.dynamic_update_slice(
-            ids, (lo + pos).reshape(1, 1).astype(jnp.int32), (0, j))
-        hit = col == pos
-        return (jnp.where(hit, _INT32_MIN, work), selected | hit, ids)
+        work, ids = carry
+        m = jnp.max(work, axis=1, keepdims=True)                  # (1, 1)
+        pos = jnp.min(jnp.where(work == m, col, seg), axis=1, keepdims=True)
+        ids = jnp.where(kcol == j, lo + pos, ids)
+        return jnp.where(col == pos, _INT32_MIN, work), ids
 
-    work0 = counts
-    sel0 = jnp.zeros((1, seg), jnp.bool_)
-    ids0 = jnp.zeros((1, k), jnp.int32)
-    _, selected, ids = jax.lax.fori_loop(0, k, body, (work0, sel0, ids0))
+    work, ids = jax.lax.fori_loop(
+        0, k, body, (counts, jnp.zeros((1, k), jnp.int32)))
     out_idx_ref[...] = ids
-    out_cnt_ref[...] = jnp.where(selected, 0, counts)
+    out_cnt_ref[...] = jnp.where(work == _INT32_MIN, 0, counts)
 
 
 @functools.partial(jax.jit,
@@ -90,42 +104,49 @@ def tracker_select(counts, indices, k: int, seg_size: int = 512,
     counts = jnp.asarray(counts, jnp.int32)
     (N,) = counts.shape
     seg = min(seg_size, max(int(N), 1))
-    if not interpret:
-        assert seg % LANE_WIDTH == 0, (
+    if not interpret and seg % LANE_WIDTH:
+        raise ValueError(
             f"seg_size {seg_size} -> effective segment {seg} is not a "
             f"multiple of the {LANE_WIDTH}-wide TPU lane dim; pick a "
             f"lane-aligned seg_size (see autotune_seg_size)")
     n_seg = -(-N // seg)                      # ceil
     k = min(k, seg)
-    assert k >= 1, k
+    if k < 1:
+        raise ValueError(f"k must be >= 1, got {k}")
     pad = n_seg * seg - N
-    # padded rows get count -1 so any live row outranks them
-    cgrid = jnp.pad(counts, (0, pad), constant_values=-1).reshape(n_seg, seg)
+    # padded rows get count -1 so any live row outranks them.  The grid
+    # axis is a squeezed leading dim: each block's last two dims, (1, seg)
+    # and (1, k), equal the array's, which is what Mosaic's tiling accepts
+    cgrid = jnp.pad(counts, (0, pad), constant_values=-1).reshape(
+        n_seg, 1, seg)
     flat = jnp.asarray(indices, jnp.int32).reshape(-1)
-    if flat.size == 0:                        # no pending ids: match nothing
-        flat = jnp.full((1,), -1, jnp.int32)
     # ids outside [0, N) must match nothing — N..n_seg*seg-1 would otherwise
     # inflate padding-row counters and displace live rows from the top-k
     flat = jnp.where((flat >= 0) & (flat < N), flat, -1)
-    idx2d = flat.reshape(-1, 1)
+    # pad the pending list with -1 (matches nothing) to whole chunks of
+    # sublane-aligned rows; an empty list becomes one chunk of 8
+    chunk = min(_PENDING_CHUNK, -(-max(flat.size, 1) // 8) * 8)
+    n_pend = -(-max(flat.size, 1) // chunk) * chunk
+    idx2d = jnp.pad(flat, (0, n_pend - flat.size),
+                    constant_values=-1).reshape(n_pend, 1)
 
     ids, new_counts = pl.pallas_call(
-        functools.partial(_kernel, seg=seg, k=k),
+        functools.partial(_kernel, seg=seg, k=k, chunk=chunk),
         grid=(n_seg,),
         in_specs=[
-            pl.BlockSpec((idx2d.shape[0], 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, seg), lambda i: (i, 0)),
+            pl.BlockSpec((n_pend, 1), lambda i: (0, 0)),
+            pl.BlockSpec((None, 1, seg), lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, seg), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, k), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, seg), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_seg, k), jnp.int32),
-            jax.ShapeDtypeStruct((n_seg, seg), jnp.int32),
+            jax.ShapeDtypeStruct((n_seg, 1, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_seg, 1, seg), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(idx2d, cgrid)
     return ids.reshape(-1), new_counts.reshape(-1)[:N]

@@ -127,15 +127,6 @@ def _dp_size(mesh):
     return sizes.get("data", 1) * sizes.get("pod", 1)
 
 
-def _cost_dict(compiled) -> dict:
-    # cost_analysis() returns a per-computation list of dicts on older
-    # jax releases and a flat dict on newer ones
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
 def run_pair(arch: str, shape_name: str, multi_pod: bool, probes: bool,
              out_dir: str):
     t0 = time.monotonic()               # duration timer, not a timestamp
@@ -185,7 +176,7 @@ def run_pair(arch: str, shape_name: str, multi_pod: bool, probes: bool,
             "fits_16GiB": bool(mem_bytes <= CHIP_HBM_BYTES),
         },
         "collectives_full_hlo": coll_full,   # scan body counted once
-        "cost_analysis_raw": {k: v for k, v in _cost_dict(compiled).items()
+        "cost_analysis_raw": {k: v for k, v in compiled.cost_analysis().items()
                               if k in ("flops", "bytes accessed")},
     })
 
@@ -195,11 +186,11 @@ def run_pair(arch: str, shape_name: str, multi_pod: bool, probes: bool,
         n_reps = cfg.num_layers // P_len
         rem = cfg.num_layers - n_reps * P_len
         l1, c1 = lower_one(probe_cfg(cfg, 1), shape_name, mesh, probe=True)
-        ca1 = _cost_dict(c1)
+        ca1 = c1.cost_analysis()
         cl1 = R.collective_bytes(c1.as_text())
         if n_reps >= 2 or rem:
             l2, c2 = lower_one(probe_cfg(cfg, 2), shape_name, mesh, probe=True)
-            ca2 = _cost_dict(c2)
+            ca2 = c2.cost_analysis()
             cl2 = R.collective_bytes(c2.as_text())
         else:
             ca2, cl2 = ca1, cl1

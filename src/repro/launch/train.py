@@ -23,6 +23,7 @@ from repro.configs import get_config, list_archs
 from repro.core import CPRManager, FailureInjector, SystemParams
 from repro.core import trackers as trk
 from repro.data.synthetic import TokenDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.optim.optimizers import apply_updates, get_optimizer
 
@@ -282,6 +283,7 @@ def main():
                          "(0 = one connection per shard; explicit "
                          "--shard-servers use host:port*k instead)")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = build_cfg(args)
     resize_at = None
     if args.resize_at:

@@ -17,18 +17,6 @@ from repro.models.layers import dense_init, init_mlp, apply_mlp
 from repro.sharding.ctx import constrain
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-compat shard_map: jax >= 0.6 exposes ``jax.shard_map`` with
-    ``check_vma``; jax 0.4.x has ``jax.experimental.shard_map.shard_map``
-    with the equivalent ``check_rep`` flag."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def init_moe(key, d_model, moe_cfg):
     m = moe_cfg
     ks = jax.random.split(key, 5)
@@ -123,8 +111,8 @@ def apply_moe_shard_map(p, x, moe_cfg, policy, capacity=None):
             gathered * sw[:, None].astype(xt.dtype))
         return out, aux
 
-    fn = _shard_map(
-        local_fn, mesh=mesh,
+    fn = jax.shard_map(
+        local_fn, mesh=mesh, check_vma=False,
         in_specs=(P(tok_spec, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),

@@ -199,7 +199,7 @@ def test_tracker_select_rejects_misaligned_seg_on_mosaic_path():
     from repro.kernels import tracker_select as ts
     counts = jnp.zeros(1000, jnp.int32)
     idx = jnp.zeros(0, jnp.int32)
-    with pytest.raises(AssertionError, match="lane"):
+    with pytest.raises(ValueError, match="lane"):
         ts.tracker_select(counts, idx, 2, seg_size=100, interpret=False)
     # interpret mode has no layout constraint: any seg runs
     ids, nc = ts.tracker_select(counts, idx, 2, seg_size=100,
@@ -214,3 +214,53 @@ def test_autotune_seg_size_picks_lane_aligned_candidate():
     assert seg in (128, 256)
     with pytest.raises(ValueError):
         ts.autotune_seg_size(4096, 8, candidates=(100, 200), trials=1)
+
+
+# ------------------------------------------- kernels the TPU refuses ------
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Make ``ops._interpret()`` see a TPU backend (nothing compiles)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("kernel", sorted(ops.NO_TPU_LOWERING))
+def test_kernel_without_tpu_lowering_refuses_on_tpu(kernel, on_tpu):
+    with pytest.raises(NotImplementedError, match=kernel):
+        ops.require_lowering(kernel)
+
+
+def test_refused_kernel_wrappers_never_interpret_on_tpu(on_tpu):
+    """On a TPU the wrappers pass interpret=False, and the kernels Mosaic
+    refuses raise instead of running the interpreter on the chip."""
+    ops.require_lowering("tracker_select")          # compiles: no refusal
+    with pytest.raises(NotImplementedError, match="row_hash"):
+        ops.row_hash(np.ones((4, 4), np.float32), np.ones((4, 1), np.float32))
+    with pytest.raises(NotImplementedError, match="ssu_dedupe"):
+        ops.ssu_dedupe_evict(jnp.zeros(4, jnp.int32), jnp.zeros(2, jnp.int32),
+                             jnp.zeros(6))
+    with pytest.raises(NotImplementedError, match="embedding_bag"):
+        ops.embedding_bag(jnp.ones((8, 16)), jnp.zeros((2, 1), jnp.int32))
+
+
+def test_refused_backends_raise_at_construction_on_tpu(on_tpu, tmp_path):
+    from repro.configs.dlrm import DLRM_KAGGLE, scaled
+    from repro.core import (CPRManager, EmbShardSpec, Emulator,
+                            FailureInjector, ShardedCheckpointWriter,
+                            SystemParams)
+    p, sizes = SystemParams(), (64, 32)
+    with pytest.raises(NotImplementedError, match="ssu_dedupe"):
+        CPRManager("cpr-ssu", p, sizes, tracker_backend="pallas")
+    with pytest.raises(NotImplementedError, match="row_hash"):
+        CPRManager("cpr-mfu", p, sizes, hash_backend="pallas")
+    tabs = [np.zeros((n, 4), np.float32) for n in sizes]
+    accs = [np.zeros((n,), np.float32) for n in sizes]
+    with pytest.raises(NotImplementedError, match="row_hash"):
+        ShardedCheckpointWriter(tabs, accs, EmbShardSpec(sizes, 2),
+                                directory=str(tmp_path),
+                                hash_backend="pallas")
+    assert not list(tmp_path.iterdir())     # refused before touching disk
+    cfg = scaled(DLRM_KAGGLE, max_rows=200)
+    mgr = CPRManager("cpr-mfu", p, cfg.table_sizes, tracker_backend="pallas")
+    with pytest.raises(NotImplementedError, match="embedding_bag"):
+        Emulator(cfg, None, mgr, FailureInjector(0, 0.25, p.N_emb,
+                                                 p.T_total), use_kernel=True)

@@ -39,3 +39,31 @@ def test_lm_driver_with_partial_recovery():
     assert "save" in kinds and "failure" in kinds
     assert np.isfinite(hist["loss"][-1][1])
     assert hist["report"]["measured_pls"] > 0
+
+
+def test_compile_cache_leaves_env_var_choice_to_jax(monkeypatch, tmp_path):
+    import jax
+    from repro.launch import compile_cache as cc
+    monkeypatch.setenv(cc.ENV_VAR, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert cc.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before   # nothing set
+
+
+def test_compile_cache_defaults_to_fixed_ignored_checkout_path(monkeypatch):
+    import os
+
+    import jax
+    from repro.launch import compile_cache as cc
+    monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = cc.enable_compile_cache()
+        assert got == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+        assert cc.enable_compile_cache() == got     # the same path each time
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

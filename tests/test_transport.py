@@ -684,3 +684,51 @@ def test_socket_severed_connection_poisons_only_that_shard(tmp_path):
         np.testing.assert_array_equal(lt[t][lo:hi], (tables[t] + 2)[lo:hi])
         lo, hi = spec.shard_range(t, 1)
         np.testing.assert_array_equal(lt[t][lo:hi], (tables[t] + 1)[lo:hi])
+
+
+def test_pipe_writer_seed_crosses_as_numpy_only(monkeypatch):
+    """A device-array leaf unpickled in a writer child would start a JAX
+    backend there, contending for the accelerator the trainer holds: the
+    spawn seed (tables, accumulators and trainer image) is numpy only."""
+    import multiprocessing as mp
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.transport import PipeEndpoint
+    spawn = mp.get_context("spawn")
+    seen = {}
+
+    class RecordingProcess:
+        pid, exitcode = None, 0
+
+        def __init__(self, target, args, name, daemon):
+            seen["args"] = args
+
+        def start(self):
+            pass
+
+        def is_alive(self):
+            return False
+
+        def join(self, timeout=None):
+            pass
+
+        def kill(self):
+            pass
+
+    class Ctx:
+        Pipe = staticmethod(spawn.Pipe)
+        Process = RecordingProcess
+
+    monkeypatch.setattr(mp, "get_context", lambda method: Ctx)
+    ep = PipeEndpoint(0, EmbShardSpec((8,), 1), [jnp.ones((8, 4))],
+                      [jnp.ones((8,))],
+                      trainer_image={"w": [jnp.ones((3, 2))],
+                                     "b": jnp.zeros(2)})
+    ep.close()
+    tabs, accs, trainer = seen["args"][4]
+    leaves = list(tabs) + list(accs) + jax.tree.leaves(trainer)
+    assert len(leaves) == 4
+    assert all(type(x) is np.ndarray for x in leaves), \
+        [type(x) for x in leaves]
