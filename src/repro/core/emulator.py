@@ -16,7 +16,6 @@ matches the no-failure accuracy.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -28,6 +27,7 @@ from repro.core import trackers as trk
 from repro.core.failure import FailureInjector
 from repro.core.manager import CPRManager
 from repro.core.sharded_checkpoint import load_latest_auto
+from repro.core.tracing import span, step_span
 from repro.metrics.classification import log_loss, roc_auc
 from repro.models import dlrm as D
 from repro.optim.optimizers import apply_updates, get_optimizer
@@ -80,21 +80,30 @@ class Emulator:
         big = mgr.big_tables if mgr.is_priority else []
         period = mgr.ssu_period
 
+        def loss_fn(p, batch):
+            # the scope sits inside the differentiated function, so backward
+            # ops carry transpose(jvp(forward)) in their op names
+            with jax.named_scope("forward"):
+                return D.dlrm_loss(p, batch, cfg, self.use_kernel)
+
         @jax.jit
         def step(params, ostate, tracker, batch):
             (loss, _), grads = jax.value_and_grad(
-                lambda p: D.dlrm_loss(p, batch, cfg, self.use_kernel),
-                has_aux=True)(params)
-            updates, ostate = opt.update(grads, ostate, params)
-            params = apply_updates(params, updates)
-            if mode == "cpr-mfu":
-                tracker = {t: trk.mfu_update(tracker[t], batch["sparse"][:, t, :])
-                           for t in big}
-            elif mode == "cpr-ssu":
-                tracker = {t: trk.ssu_update(tracker[t],
-                                             batch["sparse"][:, t, :], period,
-                                             backend=mgr.tracker_backend)
-                           for t in big}
+                loss_fn, has_aux=True)(params, batch)
+            with jax.named_scope("optimizer"):
+                updates, ostate = opt.update(grads, ostate, params)
+                params = apply_updates(params, updates)
+            with jax.named_scope("tracker"):
+                if mode == "cpr-mfu":
+                    tracker = {t: trk.mfu_update(tracker[t],
+                                                 batch["sparse"][:, t, :])
+                               for t in big}
+                elif mode == "cpr-ssu":
+                    tracker = {t: trk.ssu_update(tracker[t],
+                                                 batch["sparse"][:, t, :],
+                                                 period,
+                                                 backend=mgr.tracker_backend)
+                               for t in big}
             return params, ostate, tracker, loss
 
         return step, opt
@@ -140,39 +149,41 @@ class Emulator:
 
         t = 0.0
         loss = jnp.zeros(())
-        wall0 = time.perf_counter()
         for i, batch in enumerate(self.ds.batches(self.batch_size, tr0, tr1)):
             if i >= n_steps:
                 break
-            params, ostate, tracker, loss = step_fn(params, ostate, tracker, batch)
+            with step_span(i):
+                params, ostate, tracker, loss = step_fn(params, ostate,
+                                                        tracker, batch)
             mgr.samples_seen += self.batch_size
             t_prev, t = t, t + dt
-            # sim-hours per wall-second at the steady-state *training* rate:
-            # step 0 (jit compilation) and time already blocked inside save
-            # events are both excluded from the denominator, else the
-            # measured save cost is deflated by compile/save artifacts
-            if i == 0:
-                wall0 = time.perf_counter()
-                blocked0 = mgr.ledger.save_blocked_s
-            else:
-                train_wall = (time.perf_counter() - wall0) - \
-                    (mgr.ledger.save_blocked_s - blocked0)
-                mgr.wall_time_scale = (t - dt) / max(train_wall, 1e-9)
             for t_ev in mgr.due_saves(t):
                 tracker = mgr.run_save(
                     t_ev, params["tables"], ostate["acc"]["tables"], tracker,
                     {"bottom": params["bottom"], "top": params["top"]}, step=i)
             for ev in self.injector.between(t_prev, t):
-                new_t, new_a, _ = mgr.on_failure(
-                    ev, [np.asarray(x) for x in params["tables"]],
-                    [np.asarray(x) for x in ostate["acc"]["tables"]])
-                params = {**params,
-                          "tables": [jnp.asarray(x) for x in new_t]}
-                # rebuild via {**ostate, ...}: optimizer state beyond "acc"
-                # (momenta, step counters) must survive a failure restore
-                ostate = {**ostate,
-                          "acc": {**ostate["acc"],
-                                  "tables": [jnp.asarray(x) for x in new_a]}}
+                fid = mgr.n_failures
+                with span("cpr.restore", failure=fid,
+                          shards=",".join(map(str, ev.shard_ids))):
+                    with span("cpr.restore.fetch", failure=fid):
+                        old_t = [np.asarray(x) for x in params["tables"]]
+                        old_a = [np.asarray(x)
+                                 for x in ostate["acc"]["tables"]]
+                    mgr.counters["restore_fetch_bytes"] += sum(
+                        x.nbytes for x in old_t + old_a)
+                    with span("cpr.restore.apply", failure=fid):
+                        new_t, new_a, _ = mgr.on_failure(ev, old_t, old_a)
+                    with span("cpr.restore.put", failure=fid):
+                        params = {**params,
+                                  "tables": [jnp.asarray(x) for x in new_t]}
+                        # rebuild via {**ostate, ...}: optimizer state beyond
+                        # "acc" (momenta, step counters) must survive a
+                        # failure restore
+                        ostate = {**ostate, "acc": {
+                            **ostate["acc"],
+                            "tables": [jnp.asarray(x) for x in new_a]}}
+                    mgr.counters["restore_put_bytes"] += sum(
+                        x.nbytes for x in list(new_t) + list(new_a))
         mgr.close()   # drain + stop the async writer thread (if any)
         self.final_params = params
         self.final_ostate = ostate

@@ -22,6 +22,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.core import overhead as oh
@@ -30,6 +31,7 @@ from repro.core.checkpoint import (AsyncCheckpointWriter, CheckpointStore,
                                    EmbShardSpec)
 from repro.core.sharded_checkpoint import (ShardedCheckpointWriter,
                                            ShardSaveError)
+from repro.core.tracing import span
 from repro.core.transport import normalize_transport
 
 PRIORITY_MODES = ("cpr-mfu", "cpr-ssu", "cpr-scar")
@@ -40,20 +42,18 @@ ALL_MODES = ("full", "partial", "cpr") + PRIORITY_MODES
 class OverheadLedger:
     """Simulated-hours overhead charges.
 
-    ``save`` is the *modeled* per-bytes O_save charge (Eq. 1/2); the
-    ``save_blocked_s`` / ``save_measured`` pair is the *measured*
-    overlap-aware cost: wall-clock seconds the training thread actually
-    spent blocked inside save events (snapshotting, staging back-pressure,
-    fences — for the sync store, the whole save), and the same mapped onto
-    simulated hours via the manager's ``wall_time_scale``.  Totals stay on
-    the modeled charge so strategy comparisons remain machine-independent.
+    ``save`` is the *modeled* per-bytes O_save charge (Eq. 1/2);
+    ``save_blocked_s`` is the *measured* overlap-aware cost: wall-clock
+    seconds the training thread actually spent blocked inside save events
+    (snapshotting, staging back-pressure, fences — for the sync store, the
+    whole save).  Totals stay on the modeled charge so strategy comparisons
+    remain machine-independent.
     """
     save: float = 0.0
     load: float = 0.0
     lost: float = 0.0
     resched: float = 0.0
     save_blocked_s: float = 0.0   # measured wall seconds on the critical path
-    save_measured: float = 0.0    # the same, mapped to simulated hours
 
     @property
     def total(self):
@@ -62,8 +62,7 @@ class OverheadLedger:
     def as_dict(self, T_total=None):
         d = {"save": self.save, "load": self.load, "lost": self.lost,
              "resched": self.resched, "total": self.total,
-             "save_blocked_s": self.save_blocked_s,
-             "save_measured": self.save_measured}
+             "save_blocked_s": self.save_blocked_s}
         if T_total:
             d["fraction"] = self.total / T_total
         return d
@@ -165,10 +164,6 @@ class CPRManager:
         # sharded writer uses: "host" (numpy loop) or "pallas"
         # (kernels.row_hash, bit-exact).
         self.hash_backend = hash_backend
-        # sim-hours per wall-second of blocked save time; the emulator sets
-        # this from its measured step rate so save_measured is comparable
-        # to the modeled charges.  0 -> only raw seconds are recorded.
-        self.wall_time_scale = 0.0
 
         # ---- interval policy (paper Fig. 5) ----
         self.decision = oh.choose_strategy(sys_params, target_pls)
@@ -202,6 +197,11 @@ class CPRManager:
         self.pls = 0.0
         self.pls_by_shard = np.zeros(sys_params.N_emb)
         self.n_failures = 0
+        self.n_saves = 0
+        # bytes copied between device and host: saves count theirs here,
+        # and Emulator.run its restores' (report()["transfers"])
+        self.counters = {"save_fetch_bytes": 0, "restore_fetch_bytes": 0,
+                         "restore_put_bytes": 0}
         self.last_cycle_time = np.zeros(sys_params.N_emb)  # per-shard
         self._next_save_idx = 1       # multiples of sub-interval
         self.store = None             # CheckpointStore | ShardedCheckpointWriter
@@ -302,7 +302,6 @@ class CPRManager:
         self._total_bytes = sum(np.asarray(t).nbytes + np.asarray(a).nbytes
                                 for t, a in zip(tables, accs))
         if trainer_state is not None:
-            import jax
             self._total_bytes += sum(np.asarray(a).nbytes
                                      for a in jax.tree.leaves(trainer_state))
 
@@ -360,61 +359,82 @@ class CPRManager:
         ``pending_indices`` (cpr-mfu + pallas backend only) are accessed
         row ids per big table not yet folded into the device counters; the
         fused kernel applies them during selection.
+
+        Each stage is a ``cpr.save.*`` span (``repro.core.tracing``), and
+        every array the save brings to the host adds its ``nbytes`` to
+        ``counters["save_fetch_bytes"]``.
         """
         assert self.store is not None
         t_wall0 = time.perf_counter()
+        is_boundary = (not self.is_priority) or (
+            round(t_event / self.save_interval) % self.n_subcycles == 0)
+        sid = self.n_saves
+        self.n_saves += 1
+        with span("cpr.save", save=sid, step=step, boundary=int(is_boundary)):
+            tracker_state, nbytes = self._save(
+                sid, is_boundary, t_event, tables, accs, tracker_state,
+                trainer_state, step, pending_indices)
+        # bandwidth-proportional modeled save cost (incl. reseed fulls)
+        frac = nbytes / max(self._total_bytes, 1)
+        self.ledger.save += self.p.O_save * frac
+        # measured overlap-aware critical-path cost — everything the
+        # training thread blocked on in this event, re-admission
+        # respawn/reseed work included
+        self.ledger.save_blocked_s += time.perf_counter() - t_wall0
+        self.history.append({"t": t_event, "event": "save",
+                             "boundary": bool(is_boundary)})
+        return tracker_state
+
+    def _save(self, sid, is_boundary, t_event, tables, accs, tracker_state,
+              trainer_state, step, pending_indices):
+        """The body of ``run_save`` -> (tracker_state, bytes written)."""
         self._join_resize()         # a background reshard lands here; the
         #                             join wait counts as save-blocked time
         saver = self.writer if self.writer is not None else self.store
         nbytes = 0
-        is_boundary = (not self.is_priority) or (
-            round(t_event / self.save_interval) % self.n_subcycles == 0)
         if self.is_priority:
             # partial save of big tables by priority
             for t in self.big_tables:
                 n = self.table_sizes[t]
                 rn = max(1, int(self.r * n))
-                tab = np.asarray(tables[t])
-                acc = np.asarray(accs[t])
-                if self.mode == "cpr-mfu":
-                    if self.tracker_backend == "pallas":
-                        pend = None if pending_indices is None else \
-                            pending_indices.get(t)
-                        idx, new_counts = trk.mfu_select_segmented(
-                            tracker_state[t], rn, indices=pend,
-                            seg_size=self.seg_size)
-                        rows = np.asarray(idx)
-                        rows = rows[rows < n]       # drop padding picks
-                    else:
-                        idx, new_counts = trk.mfu_select(tracker_state[t], rn)
-                        rows = np.asarray(idx)
-                    tracker_state = {**tracker_state, t: new_counts}
-                elif self.mode == "cpr-ssu":
-                    ids, reset = trk.ssu_select(tracker_state[t])
-                    tracker_state = {**tracker_state, t: reset}
-                    rows = np.asarray(ids)
-                    rows = rows[rows != int(trk.EMPTY)]
-                else:  # cpr-scar
-                    idx, new_state = trk.scar_select(tracker_state[t],
-                                                     tables[t], rn)
-                    tracker_state = {**tracker_state, t: new_state}
-                    rows = np.asarray(idx)
+                with span("cpr.save.fetch", save=sid, table=t):
+                    tab = np.asarray(tables[t])
+                    acc = np.asarray(accs[t])
+                self.counters["save_fetch_bytes"] += tab.nbytes + acc.nbytes
+                with span("cpr.save.select", save=sid, table=t):
+                    rows, tracker_state = self._select(
+                        t, n, rn, tables, tracker_state, pending_indices)
                 if rows.size:
-                    nbytes += saver.save_rows(t, rows, tab[rows], acc[rows],
-                                              step=step)
+                    with span("cpr.save.gather", save=sid, table=t):
+                        vals, acc_vals = tab[rows], acc[rows]
+                    with span("cpr.save.store", save=sid, table=t):
+                        nbytes += saver.save_rows(t, rows, vals, acc_vals,
+                                                  step=step)
             if is_boundary:
-                for t in self.small_tables:
-                    n = self.table_sizes[t]
-                    rows = np.arange(n)
-                    nbytes += saver.save_rows(t, rows, np.asarray(tables[t]),
-                                              np.asarray(accs[t]), step=step)
-                # priority modes never run save_full, so the trainer replica
-                # (bottom/top MLPs) rides along at every cycle boundary —
-                # disk-mode recovery must not restore fresh MLPs
-                if trainer_state is not None:
-                    nbytes += saver.save_trainer(trainer_state, step=step)
+                with span("cpr.save.small", save=sid):
+                    for t in self.small_tables:
+                        rows = np.arange(self.table_sizes[t])
+                        tab = np.asarray(tables[t])
+                        acc = np.asarray(accs[t])
+                        self.counters["save_fetch_bytes"] += (tab.nbytes +
+                                                              acc.nbytes)
+                        nbytes += saver.save_rows(t, rows, tab, acc,
+                                                  step=step)
+                    # priority modes never run save_full, so the trainer
+                    # replica (bottom/top MLPs) rides along at every cycle
+                    # boundary — disk-mode recovery must not restore fresh
+                    # MLPs
+                    if trainer_state is not None:
+                        self.counters["save_fetch_bytes"] += sum(
+                            x.nbytes for x in jax.tree.leaves(trainer_state))
+                        nbytes += saver.save_trainer(trainer_state, step=step)
         else:
-            nbytes += saver.save_full(tables, accs, trainer_state, step=step)
+            with span("cpr.save.fetch", save=sid):
+                nbytes += saver.save_full(tables, accs, trainer_state,
+                                          step=step)
+            self.counters["save_fetch_bytes"] += sum(
+                x.nbytes for x in jax.tree.leaves((tables, accs,
+                                                   trainer_state)))
         if is_boundary and self.writer is not None and (
                 self.is_priority or (self.sharded_save and self.directory)):
             # a boundary completes a multi-sub-interval priority cycle: drain
@@ -426,7 +446,8 @@ class CPRManager:
             # must fence every boundary regardless: its crash-durability
             # point is the coordinator's cycle stamp, which only a fence
             # writes — without it a crash would lose the whole run's saves.
-            self.fence()
+            with span("cpr.save.fence", save=sid):
+                self.fence()
         if is_boundary:
             # a poisoned shard's saves were dropped, so its recovery point
             # (and hence its PLS/lost-time accounting) must stay at the last
@@ -458,18 +479,31 @@ class CPRManager:
                     self.history.append({"t": t_event, "event": "readmit",
                                          "shards": readmitted})
             self._maybe_tune_parity(tracker_state, t_event)
-        # bandwidth-proportional modeled save cost (incl. reseed fulls)
-        frac = nbytes / max(self._total_bytes, 1)
-        self.ledger.save += self.p.O_save * frac
-        # measured overlap-aware critical-path cost — everything the
-        # training thread blocked on in this event, re-admission
-        # respawn/reseed work included
-        blocked = time.perf_counter() - t_wall0
-        self.ledger.save_blocked_s += blocked
-        self.ledger.save_measured += blocked * self.wall_time_scale
-        self.history.append({"t": t_event, "event": "save",
-                             "boundary": bool(is_boundary)})
-        return tracker_state
+        return tracker_state, nbytes
+
+    def _select(self, t, n, rn, tables, tracker_state, pending_indices):
+        """The tracker's pick of big table ``t`` -> (row ids on the host,
+        updated tracker_state)."""
+        if self.mode == "cpr-mfu":
+            if self.tracker_backend == "pallas":
+                pend = None if pending_indices is None else \
+                    pending_indices.get(t)
+                idx, new_state = trk.mfu_select_segmented(
+                    tracker_state[t], rn, indices=pend,
+                    seg_size=self.seg_size)
+            else:
+                idx, new_state = trk.mfu_select(tracker_state[t], rn)
+        elif self.mode == "cpr-ssu":
+            idx, new_state = trk.ssu_select(tracker_state[t])
+        else:  # cpr-scar
+            idx, new_state = trk.scar_select(tracker_state[t], tables[t], rn)
+        ids = np.asarray(idx)
+        self.counters["save_fetch_bytes"] += ids.nbytes
+        if self.mode == "cpr-mfu" and self.tracker_backend == "pallas":
+            ids = ids[ids < n]              # drop padding picks
+        elif self.mode == "cpr-ssu":
+            ids = ids[ids != int(trk.EMPTY)]
+        return ids, {**tracker_state, t: new_state}
 
     def _maybe_tune_parity(self, tracker_state, t_event):
         """One-shot MFU→parity policy pass (ROADMAP item 1 stretch).
@@ -684,6 +718,7 @@ class CPRManager:
             "n_failures": self.n_failures,
             "overheads": self.ledger.as_dict(self.p.T_total),
             "bytes_written": self.store.bytes_written if self.store else 0,
+            "transfers": dict(self.counters),
             "shard_failures": sorted(self.shard_failures),
             "decision": self.decision,
         }

@@ -148,6 +148,7 @@ def tracker_select(counts, indices, k: int, seg_size: int = 512,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
+        name="tracker_select",      # the kernel's name in traces and HLO
     )(idx2d, cgrid)
     return ids.reshape(-1), new_counts.reshape(-1)[:N]
 

@@ -135,13 +135,6 @@ def train(cfg, steps=200, batch=8, seq=128, lr=0.005, mode="cpr-mfu",
             break
         params, ostate, tracker, loss = step_fn(params, ostate, tracker, b)
         mgr.samples_seen += batch
-        if i == 0:      # step 0 is jit compile; time the steady-state rate
-            t_steady = time.monotonic()
-            blocked0 = mgr.ledger.save_blocked_s
-        else:           # exclude time already blocked inside save events
-            train_wall = (time.monotonic() - t_steady) - \
-                (mgr.ledger.save_blocked_s - blocked0)
-            mgr.wall_time_scale = i / max(train_wall, 1e-9)
         t_prev, t_sim = t_sim, t_sim + 1.0
         if resize_at and i in resize_at:
             # live fleet resize under traffic: the reshard overlaps
