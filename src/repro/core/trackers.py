@@ -58,11 +58,14 @@ def mfu_select_segmented(counts, rn: int, indices=None, seg_size: int = 512):
     """Device-side fused MFU update + segment-wise top-k (Pallas kernel).
 
     Replaces the global ``top_k`` over the full counter table with a
-    per-segment top-``ceil(rn/n_seg)`` selection; ``indices`` (optional
-    pending accessed ids not yet counted) are folded in by the same kernel,
-    so priority saves never round-trip the table through a host sort.
-    Selected ids may include padding picks >= N; callers drop those.
-    Returns (row_ids, new_counts) like ``mfu_select``.
+    per-segment top-``ceil(rn/n_seg)`` selection, so priority saves never
+    round-trip the table through a host sort.  ``indices`` (optional
+    pending accessed ids not yet counted; ids outside [0, N) are dropped)
+    are folded in by a scatter-add in the same jitted program, before the
+    kernel.  Returns (row_ids, new_counts) like ``mfu_select``: row_ids
+    hold each segment's picks in descending count, ties to the lowest id,
+    and may include padding picks >= N, which callers drop; new_counts
+    hold the folded counts with the picked rows cleared.
 
     Caveat: the per-segment quota matches global top-k only when hot rows
     are spread across segments.  Ids clustered into few segments (e.g. raw

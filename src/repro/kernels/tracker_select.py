@@ -1,4 +1,4 @@
-"""Pallas-TPU fused MFU tracker update + segment-wise top-k row selection.
+"""Pallas-TPU MFU tracker update + segment-wise top-k row selection.
 
 The CPR-MFU hot path at every priority-save sub-interval is: fold the
 pending accessed-row ids into the per-row access counters, then pick the
@@ -9,18 +9,21 @@ this kernel keeps everything on device and replaces the global sort with a
 top ``k`` rows of each segment are selected.  For skewed (Zipf-like) access
 distributions hot rows are spread across segments, so segment-wise
 selection covers the same hot set while needing only an O(seg) scan per
-grid step — no global argsort, no host round-trip.
+segment — no global argsort, no host round-trip.
 
-Grid: one step per segment, over counters laid out (n_seg, 1, seg) with
-the segment axis squeezed out of the block, so every block's last two
-dims equal the array's (Mosaic's tiling rule).  Each step
-  1. DMAs its (1, seg) counter block into VMEM,
-  2. adds the pending-id histogram for its row range, comparing the
-     pending id list against the segment's global row iota ``chunk`` ids
-     at a time (a fixed-size VMEM compare buffer at any pending count),
-  3. runs ``k`` max/argmin-of-tie iterations to emit the segment's top-k
-     global row ids,
-  4. writes back the updated counters with the selected rows cleared.
+Layout: the padded counters are viewed as ``(n_seg_p, seg)``, one segment
+per row, and the grid runs over blocks of ``S`` segments stacked on
+sublanes.  ``S`` is derived from the shape alone (``_block_segments``):
+about ``_BLOCK_ROWS`` counters per block, a multiple of the 8 sublanes, and
+no more segments than the table has.  ``n_seg`` is padded up to whole
+blocks with segments of count -1, like the ragged last segment.  Each grid
+step runs ``k`` max/argmin-of-tie iterations over its ``(S, seg)`` block,
+so every iteration's row-wise reductions cover ``S`` independent segments
+at once, emits the ``(S, k)`` top-k global row ids, and writes back the
+counters with the selected rows cleared.
+
+Pending ids are folded in by one scatter-add in the same jitted program,
+before the ``pallas_call``; ids outside ``[0, N)`` are dropped.
 
 ``interpret=True`` (off the TPU) runs the same kernel body as traced JAX
 ops — bit-identical to the Mosaic path and to ``ref.tracker_select``.
@@ -39,48 +42,48 @@ from jax.experimental.pallas import tpu as pltpu
 
 _INT32_MIN = jnp.iinfo(jnp.int32).min
 
-# TPU vector lane width: a Mosaic-lowered (1, seg) block lives in
+# TPU vector lane width: a Mosaic-lowered (S, seg) block lives in
 # (sublane, lane) tiles, so ``seg`` must be a lane-width multiple or the
 # compile fails with an opaque layout error.  interpret mode has no such
 # constraint (any seg runs), which is exactly how a blind-tuned seg_size
 # slips through CPU tests and breaks on hardware — hence the guard below.
 LANE_WIDTH = 128
+SUBLANES = 8
 
-# pending ids folded per inner step: bounds the kernel's (chunk, seg)
-# compare buffer in VMEM (512 x 512 int32 = 1 MiB) at any pending count
-_PENDING_CHUNK = 512
+# counters per grid step: 2**17 int32 is 128 dense vregs, enough
+# independent segments that the row-wise reductions pipeline, and its
+# double-buffered in/out blocks (~2 MiB) sit well inside scoped VMEM
+_BLOCK_ROWS = 1 << 17
 
 
-def _kernel(idx_ref, cnt_ref, out_idx_ref, out_cnt_ref, *, seg: int, k: int,
-            chunk: int):
-    lo = pl.program_id(0) * seg
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, seg), 1)        # 0..seg-1
-    gid = lo + col                                                # global ids
-    # --- fused count update: histogram of pending ids over this segment,
-    # folded ``chunk`` ids at a time so the (chunk, seg) compare buffer
-    # stays a fixed VMEM size whatever the pending count ---
-    def fold(c, hits):
-        start = pl.multiple_of(c * chunk, chunk)
-        ids = idx_ref[pl.ds(start, chunk), :]                     # (chunk, 1)
-        return hits + jnp.sum((ids == gid).astype(jnp.int32), axis=0,
-                              keepdims=True)                      # (1, seg)
-    n_chunks = idx_ref.shape[0] // chunk
-    counts = cnt_ref[...] + jax.lax.fori_loop(
-        0, n_chunks, fold, jnp.zeros((1, seg), jnp.int32))
+def _block_segments(n_seg: int, seg: int) -> int:
+    """Segments per grid step: a sublane multiple near ``_BLOCK_ROWS``
+    counters, and no more than ``n_seg`` rounded up to whole sublanes."""
+    per_block = max(SUBLANES, _BLOCK_ROWS // seg // SUBLANES * SUBLANES)
+    return min(-(-n_seg // SUBLANES) * SUBLANES, per_block)
 
-    # --- segment-wise top-k (ties -> lowest row id); a picked row's work
-    # entry drops to INT32_MIN, which no live or padding count reaches ---
-    kcol = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
+def _kernel(cnt_ref, out_idx_ref, out_cnt_ref, *, k: int):
+    s, seg = cnt_ref.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (s, seg), 1)       # 0..seg-1
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (s, k), 1)
+    # first global row id of each of the block's segments
+    lo = (pl.program_id(0) * s +
+          jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)) * seg
+    counts = cnt_ref[...]
+
+    # --- segment-wise top-k (ties -> lowest row id), one segment per
+    # row; a picked row's work entry drops to INT32_MIN, which no live or
+    # padding count reaches ---
     def body(j, carry):
         work, ids = carry
-        m = jnp.max(work, axis=1, keepdims=True)                  # (1, 1)
+        m = jnp.max(work, axis=1, keepdims=True)                  # (s, 1)
         pos = jnp.min(jnp.where(work == m, col, seg), axis=1, keepdims=True)
         ids = jnp.where(kcol == j, lo + pos, ids)
         return jnp.where(col == pos, _INT32_MIN, work), ids
 
     work, ids = jax.lax.fori_loop(
-        0, k, body, (counts, jnp.zeros((1, k), jnp.int32)))
+        0, k, body, (counts, jnp.zeros((s, k), jnp.int32)))
     out_idx_ref[...] = ids
     out_cnt_ref[...] = jnp.where(work == _INT32_MIN, 0, counts)
 
@@ -93,13 +96,14 @@ def tracker_select(counts, indices, k: int, seg_size: int = 512,
 
     counts:  (N,) int32 per-row access counters.
     indices: int array of pending accessed row ids (any shape; may be empty)
-             not yet folded into ``counts``.
+             not yet folded into ``counts``; ids outside [0, N) are dropped.
     k:       rows to select per segment.
 
     Returns ``(row_ids, new_counts)``: ``row_ids`` is (n_seg * k,) int32
-    global ids (entries >= N are padding-segment picks and must be dropped
-    by the caller); ``new_counts`` is (N,) with pending ids folded in and
-    the selected rows' counters cleared.
+    global ids, per segment in descending count with ties to the lowest
+    id (entries >= N are padding-segment picks and must be dropped by the
+    caller); ``new_counts`` is (N,) with pending ids folded in and the
+    selected rows' counters cleared.
     """
     counts = jnp.asarray(counts, jnp.int32)
     (N,) = counts.shape
@@ -113,44 +117,36 @@ def tracker_select(counts, indices, k: int, seg_size: int = 512,
     k = min(k, seg)
     if k < 1:
         raise ValueError(f"k must be >= 1, got {k}")
-    pad = n_seg * seg - N
-    # padded rows get count -1 so any live row outranks them.  The grid
-    # axis is a squeezed leading dim: each block's last two dims, (1, seg)
-    # and (1, k), equal the array's, which is what Mosaic's tiling accepts
-    cgrid = jnp.pad(counts, (0, pad), constant_values=-1).reshape(
-        n_seg, 1, seg)
     flat = jnp.asarray(indices, jnp.int32).reshape(-1)
-    # ids outside [0, N) must match nothing — N..n_seg*seg-1 would otherwise
-    # inflate padding-row counters and displace live rows from the top-k
-    flat = jnp.where((flat >= 0) & (flat < N), flat, -1)
-    # pad the pending list with -1 (matches nothing) to whole chunks of
-    # sublane-aligned rows; an empty list becomes one chunk of 8
-    chunk = min(_PENDING_CHUNK, -(-max(flat.size, 1) // 8) * 8)
-    n_pend = -(-max(flat.size, 1) // chunk) * chunk
-    idx2d = jnp.pad(flat, (0, n_pend - flat.size),
-                    constant_values=-1).reshape(n_pend, 1)
+    if flat.size:
+        # a negative id would wrap to the table's end: send every id
+        # outside [0, N) to N, which the scatter drops
+        flat = jnp.where((flat >= 0) & (flat < N), flat, N)
+        counts = counts.at[flat].add(1, mode="drop")
+    s = _block_segments(n_seg, seg)
+    n_seg_p = -(-n_seg // s) * s
+    # padded rows get count -1 so any live row outranks them
+    cgrid = jnp.pad(counts, (0, n_seg_p * seg - N),
+                    constant_values=-1).reshape(n_seg_p, seg)
 
     ids, new_counts = pl.pallas_call(
-        functools.partial(_kernel, seg=seg, k=k, chunk=chunk),
-        grid=(n_seg,),
-        in_specs=[
-            pl.BlockSpec((n_pend, 1), lambda i: (0, 0)),
-            pl.BlockSpec((None, 1, seg), lambda i: (i, 0, 0)),
-        ],
+        functools.partial(_kernel, k=k),
+        grid=(n_seg_p // s,),
+        in_specs=[pl.BlockSpec((s, seg), lambda i: (i, 0))],
         out_specs=[
-            pl.BlockSpec((None, 1, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((None, 1, seg), lambda i: (i, 0, 0)),
+            pl.BlockSpec((s, k), lambda i: (i, 0)),
+            pl.BlockSpec((s, seg), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_seg, 1, k), jnp.int32),
-            jax.ShapeDtypeStruct((n_seg, 1, seg), jnp.int32),
+            jax.ShapeDtypeStruct((n_seg_p, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_seg_p, seg), jnp.int32),
         ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("parallel",)),
         name="tracker_select",      # the kernel's name in traces and HLO
-    )(idx2d, cgrid)
-    return ids.reshape(-1), new_counts.reshape(-1)[:N]
+    )(cgrid)
+    return ids[:n_seg].reshape(-1), new_counts.reshape(-1)[:N]
 
 
 def autotune_seg_size(n_rows: int, k: int,

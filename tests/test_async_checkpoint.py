@@ -183,17 +183,37 @@ def test_async_disk_roundtrip(tmp_path):
 
 
 # ------------------------------------------------- tracker_select kernel ---
-@pytest.mark.parametrize("N,M,k,seg", [
-    (1000, 300, 25, 256),    # multi-segment
-    (7, 3, 2, 512),          # single tiny segment
-    (512, 0, 10, 128),       # no pending ids
-    (513, 11, 4, 256),       # ragged last segment (padding picks)
-    (100, 50, 100, 512),     # k > live rows
-])
-def test_tracker_select_matches_numpy_ref(N, M, k, seg):
+_SELECT_CASES = [
+    (1000, 300, 25, 256, False),    # multi-segment
+    (7, 3, 2, 512, False),          # single tiny segment
+    (512, 0, 10, 128, False),       # no pending ids
+    (513, 11, 4, 256, False),       # ragged last segment (padding picks)
+    (100, 50, 100, 512, False),     # k > live rows
+    # several blocks of segments, n_seg (1172) not a multiple of the block
+    (300_001, 500, 3, 256, False),
+    # ragged last segment inside a block padded with whole segments
+    (4658, 200, 5, 128, False),
+    (20_000, 300, 1, 512, False),   # k = 1
+    (1000, 100, 128, 128, False),   # k = seg: every row of every segment
+    (50_000, 1000, 8, 512, False),  # a table smaller than one block
+    # pending ids past the end and negative, folded by the scatter
+    (4658, 200, 5, 128, True),
+]
+
+
+@pytest.mark.parametrize(
+    "N,M,k,seg,stray", _SELECT_CASES,
+    ids=["-".join(map(str, c[:4])) + ("-stray" if c[4] else "")
+         for c in _SELECT_CASES])
+def test_tracker_select_matches_numpy_ref(N, M, k, seg, stray):
     rng = np.random.default_rng(N + M + k)
     counts = rng.integers(0, 50, size=N).astype(np.int32)
     idx = rng.integers(0, N, size=M).astype(np.int32)
+    if stray:
+        idx = np.concatenate([idx, np.array(
+            [N, N + 7, -1, -N, -(N + 3), np.iinfo(np.int32).max,
+             np.iinfo(np.int32).min], np.int32)])
+        rng.shuffle(idx)
     got_i, got_c = ops.tracker_select(jnp.asarray(counts), jnp.asarray(idx),
                                       k, seg_size=seg)
     want_i, want_c = ref.tracker_select(counts, idx, k, seg_size=seg)

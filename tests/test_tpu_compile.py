@@ -74,14 +74,15 @@ def test_dlrm_kaggle_mfu_train_step_fits_one_chip(one_chip):
     assert total < HBM_BYTES, mem
 
 
-def test_tracker_select_compiles_through_mosaic(one_chip):
-    """The Pallas MFU selection kernel at the largest Kaggle table, with a
-    batch of pending ids, lowers to a TPU custom call (not the
-    interpreter)."""
+@pytest.mark.parametrize("n", [286_181, 10_131_227])
+def test_tracker_select_compiles_through_mosaic(one_chip, n):
+    """The Pallas MFU selection kernel at the smallest and the largest
+    Kaggle big table, with a batch of pending ids, lowers to a TPU custom
+    call (not the interpreter): its blocks of many segments fit VMEM, which
+    Mosaic would refuse otherwise."""
     from repro.core import trackers as trk
     from repro.kernels import tracker_select as ts
 
-    n = 10_131_227
     seg, k = trk.segmented_k(n, int(0.125 * n), 512)
     counts = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
     pending = jax.ShapeDtypeStruct((2048,), jnp.int32, sharding=one_chip)
