@@ -33,11 +33,20 @@ What the window itself did, exactly (limit 0):
 - ``reservoir_mismatch`` (cpr-ssu): rows a priority save stored that no
   sub-sampled access since the last save named, rows missing where the
   reservoir never overflowed, and slots short where it did;
-- ``restore_mismatch`` (priority modes): after each partial restore, rows
-  outside the failed shards that changed, and rows inside them, never saved,
-  that are not back at their initial value and accumulator;
+- ``restore_mismatch``: after each partial restore, rows outside the
+  failed shards that changed, and rows inside them, never saved, that are
+  not back at their initial value and accumulator; after each full-recovery
+  restore, which replays from the last full save and so hands back the
+  state the failure found, the rows of every table and accumulator on the
+  device that differ from what they were before the restore's fetch;
 - ``full_save_bytes_gap`` (full): bytes of a full save other than every
-  table, accumulator and dense layer.
+  table, accumulator and dense layer;
+- ``full_image_mismatch`` (full): after each full save, rows of the
+  store's image that differ from the device state the save was given, over
+  ``harness.IMAGE_SAMPLES`` rows of each table and accumulator drawn from
+  the seed among the rows training moves, and trainer arrays that differ;
+  after the window, tables and accumulators of the image whose digest
+  differs from the device state's at the last save.
 """
 from __future__ import annotations
 
@@ -94,9 +103,12 @@ def big_tables(sizes, coverage=0.99):
 
 
 def _ids(cell, t, steps):
+    """Table ``t``'s ids fed at ``steps``, every slot of every sample in the
+    order the step flattens them (duplicates kept, as the counts take
+    them)."""
     B, P = cell.batch, cell.n_train_batches
     sp = cell.pool["sparse"]
-    return np.concatenate([sp[(j % P) * B:(j % P + 1) * B, t, 0]
+    return np.concatenate([sp[(j % P) * B:(j % P + 1) * B, t].reshape(-1)
                            for j in steps]) if len(steps) else \
         np.zeros(0, np.int32)
 
@@ -176,12 +188,11 @@ def reservoir_mismatch(cell, probes):
     got = _rows_by_save(probes)
     big = big_tables(sizes)
     period = int(tr["ssu_period"])
-    B = cell.batch
     bad = 0
     for e, steps in _intervals(probes):
         for t in big:
             rn = max(1, int(tr["r"] * sizes[t]))
-            cand = np.concatenate([_ids(cell, t, [j])[0:B:period]
+            cand = np.concatenate([_ids(cell, t, [j])[::period]
                                    for j in steps]) if len(steps) else \
                 np.zeros(0, np.int32)
             want = np.unique(cand)
@@ -202,7 +213,7 @@ def _slices_outside(n, ranges):
     return [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
 
 
-def _rows_differ(a, b):
+def rows_differ(a, b):
     if np.array_equal(a, b):
         return 0
     d = a != b
@@ -263,8 +274,8 @@ def restore_mismatch(cell, probes, ref, picks, union, init_values,
         for t, n in enumerate(sizes):
             ranges = [ref.shard_range(n, j, n_emb) for j in shards]
             for a, b in _slices_outside(n, ranges):
-                d["outside_rows"] += _rows_differ(new_t[t][a:b], old_t[t][a:b])
-                d["outside_accs"] += _rows_differ(new_a[t][a:b], old_a[t][a:b])
+                d["outside_rows"] += rows_differ(new_t[t][a:b], old_t[t][a:b])
+                d["outside_accs"] += rows_differ(new_a[t][a:b], old_a[t][a:b])
             unsaved, rows = per[t]
             d["unsaved_accs"] += int(np.count_nonzero(new_a[t][unsaved]))
             want = init_values[t][np.searchsorted(union[t], rows)]
@@ -274,6 +285,21 @@ def restore_mismatch(cell, probes, ref, picks, union, init_values,
     if detail is not None:
         detail.update(d)
     return sum(v for k, v in d.items() if k != "unsaved_sampled")
+
+
+def full_restore_mismatch(probes):
+    """Rows a full-recovery restore changed; a failure whose restored state
+    was never read counts one."""
+    return sum(f.get("rows_changed", 1) for f in probes.failures)
+
+
+def full_image_mismatch(probes, arrays_differ):
+    """Image rows and trainer arrays unlike the device state, over every
+    save, plus the image's arrays unlike the last save's state
+    (``arrays_differ``); a reading that is missing counts one."""
+    bad = sum(r.get("image_rows_differ", 1) for r in probes.saves
+              if not r.get("skipped"))
+    return bad + (1 if arrays_differ is None else arrays_differ)
 
 
 def full_save_bytes_gap(cell, probes, ref):

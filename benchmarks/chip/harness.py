@@ -28,9 +28,14 @@ jitted step, and wrappers sit on the manager's ``run_save``,
 event clock starts after the device has drained, and each probe opens a
 ``jax.profiler.TraceAnnotation`` (``bench.step``, ``bench.save``,
 ``bench.failure``, ``bench.batch``, ``bench.eval``, ``bench.window``).
+Under full recovery the probes also read, after each save's clock, the
+store's image at rows drawn from the seed against the device state the save
+was given, and after each restore's clock the device state handed back
+against the one the failure found (``checks.py``).
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib.util
 import json
 import os
@@ -47,12 +52,18 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 if HERE not in sys.path:
     sys.path.insert(0, HERE)
 
+import checks  # noqa: E402
 import pool as pool_mod  # noqa: E402
 
 WINDOW_STEP = 3          # steps 0..2 are read by the check, before the window
 SPAN_SLACK = 1e-9        # the last save lands at the span's end, not past it
+IMAGE_SAMPLES = 4096     # pool samples whose rows each full save is read at
 # the check's control: the reference one precision below the configuration's
 CONTROL_PRECISION = {"highest": "bf16_3x"}
+# keys of a configuration file that the harness reads, not the model; every
+# other key names a field of the program's ``DLRMConfig``
+RUN_KEYS = ("arch", "batch_size", "param_dtype", "matmul_precision",
+            "optimizer", "reduced", "assumed", "deployment")
 
 
 # ------------------------------------------------------------ discovery ----
@@ -92,6 +103,24 @@ def _module(path, name):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def _frozen(v):
+    return tuple(_frozen(x) for x in v) if isinstance(v, list) else v
+
+
+def program_config(cfg):
+    """The program's ``DLRMConfig`` from every model key of a configuration
+    file; a key that is neither a field of it nor a run key is refused."""
+    from repro.models.dlrm import DLRMConfig
+    fields = {f.name for f in dataclasses.fields(DLRMConfig)}
+    unknown = sorted(set(cfg) - fields - set(RUN_KEYS))
+    if unknown:
+        raise ValueError(f"configuration {cfg.get('name')!r}: key(s) "
+                         f"{', '.join(unknown)} name no field of the "
+                         f"program's DLRMConfig and no run key {RUN_KEYS}")
+    return DLRMConfig(**{k: _frozen(v) for k, v in cfg.items()
+                         if k in fields})
 
 
 def metric_reader(name):
@@ -177,11 +206,11 @@ class Probes:
     """Clocks, counters and the check's readings of one ``Emulator.run``."""
 
     def __init__(self, arch, ref, seed, first_rows, faults=(), record=False,
-                 trace_dir=None):
+                 trace_dir=None, image_rows=None):
         self.jax = jax
         self.arch, self.ref, self.faults = arch, ref, set(faults)
         self.first_rows = [jnp.asarray(r, jnp.int32) for r in first_rows]
-        self.key = jax.random.PRNGKey(seed)
+        self.seed, self.key = seed, jax.random.PRNGKey(seed)
         self.record, self.trace_dir = record, trace_dir
         self.i = 0                       # step calls so far
         self.latest = None               # outputs of the newest step
@@ -199,6 +228,13 @@ class Probes:
         self.stop_at_window = False
         self.run_saves = None            # save indices to run (None: all)
         self.memory = []                 # (where, bytes in use, peak)
+        # full recovery: rows of each table whose saved image every save's
+        # check reads (None: another mode), and the device state's digest at
+        # the newest save, which the whole image is held to after the window
+        self.image_rows = image_rows
+        self.image_rows_dev = None if image_rows is None else [
+            jnp.asarray(r, jnp.int32) for r in image_rows]
+        self.image_digest = None
 
     # -- helpers --
     def _note_memory(self, where):
@@ -255,7 +291,15 @@ class Probes:
             i = self.i
             if self.pending is not None:
                 jax.block_until_ready((params, ostate))
+                rec = self.pending[2]
                 self._end_failure()
+                if self.image_rows is not None:
+                    # full recovery replays from the last full save, so it
+                    # hands back the state the failure found, row for row
+                    rec["rows_changed"] = int(rows_changed(
+                        (self.latest[0]["tables"],
+                         self.latest[1]["acc"]["tables"]),
+                        (params["tables"], ostate["acc"]["tables"])))
             if i == WINDOW_STEP:
                 if self.stop_at_window:
                     raise StopRun()
@@ -318,6 +362,8 @@ class Probes:
             rec["s"] = time.perf_counter() - t0
             rec["bytes"] = mgr.store.bytes_written - b0
             self._note_memory(f"save {len(self.saves) - 1}")
+            if self.image_rows is not None:
+                self._check_image(rec, tables, accs, trainer_state)
             return out
 
         def probed_between(t0, t1):
@@ -333,12 +379,14 @@ class Probes:
 
         def probed_failure(event, tables, accs):
             new_t, new_a, info = on_failure(event, tables, accs)
-            if "alter_restore" in self.faults and new_t is not tables:
+            if "alter_restore" in self.faults:
                 t = int(np.argmax([len(x) for x in new_t]))
                 j = min(set(range(mgr.p.N_emb)) - set(event.shard_ids))
                 lo, _ = self.ref.shard_range(len(new_t[t]), j, mgr.p.N_emb)
+                new_t = list(new_t)
+                new_t[t] = np.array(new_t[t])
                 new_t[t][lo] += 1.0
-            if self.record:
+            if self.record and self.image_rows is None:
                 self.failure_states.append(
                     {"save_index": len(self.saves), "shards": event.shard_ids,
                      "old": (tables, accs), "new": (new_t, new_a)})
@@ -365,6 +413,38 @@ class Probes:
         mgr.attach_store = probed_attach
         injector.between = probed_between
 
+    def _check_image(self, rec, tables, accs, trainer_state):
+        """What a full save stored against the device state it was given:
+        the sampled rows of every table and accumulator and the whole
+        trainer replica now; a digest of the whole state for the check
+        after the window."""
+        store = self.mgr.store
+        if "alter_image" in self.faults:
+            t = int(np.argmax([len(x) for x in store.image_tables]))
+            rng = np.random.default_rng([self.seed, 0x1A6E, len(self.saves)])
+            store.image_tables[t][rng.integers(len(store.image_tables[t]))] \
+                += 1.0
+        got_t, got_a = jax.device_get(take_rows(tables, accs,
+                                                self.image_rows_dev))
+        bad = sum(checks.rows_differ(gt, it[r]) + checks.rows_differ(ga, ia[r])
+                  for gt, ga, it, ia, r in zip(got_t, got_a, store.image_tables,
+                                               store.image_accs,
+                                               self.image_rows))
+        bad += sum(not np.array_equal(np.asarray(a), b) for a, b in zip(
+            jax.tree.leaves(trainer_state),
+            jax.tree.leaves(store.trainer_image)))
+        rec["image_rows_differ"] = bad
+        self.image_digest = state_digest(list(tables) + list(accs))
+
+    def image_arrays_differ(self, store):
+        """Tables and accumulators of the store's image whose digest differs
+        from the device state's at the newest save (None: no save)."""
+        if self.image_digest is None:
+            return None
+        image = list(store.image_tables) + list(store.image_accs)
+        return int(np.count_nonzero(np.asarray(self.image_digest) !=
+                                    np.asarray(state_digest(image))))
+
     # -- readings --
     def readings(self):
         """The program's side of the training check, as host numbers."""
@@ -389,6 +469,31 @@ class Probes:
 
 
 @jax.jit
+def rows_changed(a, b):
+    """Rows of the arrays of ``a`` that differ from those of ``b``."""
+    return sum(jnp.count_nonzero((x != y).reshape(x.shape[0], -1).any(axis=1))
+               for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
+@jax.jit
+def take_rows(tables, accs, rows):
+    """The rows of every table and accumulator, stacked: two transfers."""
+    return (jnp.stack([x[r] for x, r in zip(tables, rows)]),
+            jnp.stack([x[r] for x, r in zip(accs, rows)]))
+
+
+@jax.jit
+def state_digest(arrays):
+    """Per float32 array, the sum of its words times the odd weights 2i + 1,
+    modulo 2**32: a single changed word always changes it."""
+    def one(x):
+        w = jax.lax.bitcast_convert_type(x, jnp.uint32).reshape(-1)
+        i = jnp.arange(w.size, dtype=jnp.uint32)
+        return jnp.sum(w * (2 * i + 1), dtype=jnp.uint32)
+    return jnp.stack([one(x) for x in arrays])
+
+
+@jax.jit
 def acc_grad_norms(acc, params):
     """Per-leaf gradient norm of a first row-wise Adagrad step, from its
     state: a 2-D leaf's accumulator is the row mean of the squared gradient,
@@ -409,31 +514,36 @@ def shard_sets(seed, n_events, n_shards, per_event):
 
 class Cell:
     def __init__(self, cfg, traffic, seed, faults=()):
-        from repro.models.dlrm import DLRMConfig
         self.cfg, self.traffic, self.seed = cfg, traffic, int(seed)
         self.faults = tuple(faults)
+        self.dcfg = program_config(cfg)
         self.ref = reference(cfg["arch"])
         self.arch = self.ref.Arch.of(cfg)
-        self.dcfg = DLRMConfig(
-            name=cfg["name"], num_dense=cfg["num_dense"],
-            table_sizes=tuple(cfg["table_sizes"]), emb_dim=cfg["emb_dim"],
-            bottom_mlp=tuple(cfg["bottom_mlp"]),
-            top_mlp=tuple(cfg["top_mlp"]), multi_hot=1)
         self.batch = int(cfg["batch_size"])
         p = traffic["pool"]
         self.n_train_batches = int(p["train_batches"])
         self.pool = pool_mod.make_pool(
             cfg["table_sizes"], cfg["num_dense"],
             self.n_train_batches * self.batch + int(p["eval_samples"]),
-            self.seed, zipf_a=p["zipf_a"])
+            self.seed, zipf_a=p["zipf_a"], multi_hot=self.dcfg.multi_hot)
         sp = self.pool_batch(0)["sparse"]
         # the rows step 0 touches: the check compares their first gradients
         self.first_rows = [np.unique(sp[:, t, :])
                            for t in range(sp.shape[1])]
+        self.image_rows = None
+        if traffic["mode"] == "full":
+            # the rows each full save's check reads: a table's ids in
+            # IMAGE_SAMPLES pool samples drawn from the seed, so the rows
+            # that training moves are in it
+            rng = np.random.default_rng([self.seed, 0x1A6E])
+            pick = rng.integers(0, self.n_train_batches * self.batch,
+                                IMAGE_SAMPLES)
+            self.image_rows = [self.pool["sparse"][pick, t, 0]
+                               for t in range(sp.shape[1])]
 
     def probes(self, **kw):
         return Probes(self.arch, self.ref, self.seed, self.first_rows,
-                      self.faults, **kw)
+                      self.faults, image_rows=self.image_rows, **kw)
 
     def pool_batch(self, i):
         """The batch that step ``i`` of a run is fed."""

@@ -1,7 +1,8 @@
 """Model FLOP/s utilization of the train step: the forward and backward
-operations of the MLPs and the interaction per sample, times the samples of
-the traced steps, over the step program's own device time (``jit_step``, as
-``step_ms`` reads it) and the chip's bf16 peak."""
+operations of a sample as the configuration's reference counts them
+(``train_flops_per_sample``), times the samples of the traced steps, over
+the step program's own device time (``jit_step``, as ``step_ms`` reads it)
+and the chip's bf16 peak.  Silent where the reference has no count."""
 
 MODULE = "jit_step"
 
@@ -12,5 +13,7 @@ def read(ctx):
     if not m or not m["count"] or m["s"] <= 0 or not peaks:
         return None
     flops = ctx["yardstick"].dlrm_train_flops_per_sample(ctx["cfg"])
+    if flops is None:
+        return None
     rate = flops * c["batch"] * m["count"] / m["s"]
     return 100.0 * rate / peaks["bf16_flops_per_s"]

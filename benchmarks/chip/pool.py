@@ -1,14 +1,20 @@
 """Seeded click-log pool: the benchmark's traffic generator.
 
 A Criteo-like log as ``repro.data.synthetic.ClickLogDataset`` makes it: 13
-dense features from a standard normal, one categorical id per table whose
-rank is Zipf-distributed (``zipf_a``, 1.2 by default) and mapped to a row by
-a per-table bijection so that the hot rows differ per table and spread over
-the table, and labels from a hidden logistic teacher.  Two departures keep it
+dense features from a standard normal, categorical ids whose ranks are
+Zipf-distributed (``zipf_a``, 1.2 by default) and mapped to rows by a
+per-table bijection so that the hot rows differ per table and spread over
+the table, and labels from a hidden logistic teacher whose per-table effect
+is the mean of the effects of the table's ids.  Two departures keep it
 cheap at 34M rows: the per-table permutation is an affine bijection
 ``(a * rank + b) mod n`` with ``gcd(a, n) = 1`` instead of a stored
 permutation, and the teacher's per-id effect is a hash of the id instead of a
 stored vector.  Every seed gives the same sizes; only the draws differ.
+
+Hotness (``multi_hot``, lookups per table and sample, the program's
+``DLRMConfig.multi_hot``) gives ``sparse (S, F, h)``.  Slot 0 of every table
+is the one-hot pool's draw, from the same stream; further slots come from a
+second stream, so a one-hot pool is the same whatever the hotness.
 
 ``PoolDataset`` cycles the pool so that ``Emulator.run`` can train for any
 number of steps, and tells the harness when the first evaluation batch is
@@ -45,24 +51,33 @@ def affine_bijection(rng, n):
     return a, int(rng.integers(0, n))
 
 
-def make_pool(table_sizes, num_dense, n_samples, seed, zipf_a=1.2):
-    """-> dict(dense (S, num_dense) f32, sparse (S, F, 1) i32, label (S,) f32)."""
+def make_pool(table_sizes, num_dense, n_samples, seed, zipf_a=1.2,
+              multi_hot=1):
+    """-> dict(dense (S, num_dense) f32, sparse (S, F, h) i32, label (S,)
+    f32)."""
     rng = np.random.default_rng([int(seed), 0x5EED])
+    more = np.random.default_rng([int(seed), 0x5EED, 1])
     F = len(table_sizes)
+    h = int(multi_hot)
     dense = rng.standard_normal((n_samples, num_dense), dtype=np.float32)
-    sparse = np.empty((n_samples, F, 1), np.int32)
+    sparse = np.empty((n_samples, F, h), np.int32)
     teacher_dense = rng.standard_normal(num_dense) / np.sqrt(num_dense)
     logits = dense.astype(np.float64) @ teacher_dense - 0.3
     salt = np.uint64(rng.integers(0, 1 << 62))
     for f, n in enumerate(table_sizes):
         ranks = np.minimum(rng.zipf(zipf_a, size=n_samples) - 1, n - 1)
         a, b = affine_bijection(rng, int(n))
+        ranks = ranks[:, None]
+        if h > 1:
+            extra = np.minimum(
+                more.zipf(zipf_a, size=(n_samples, h - 1)) - 1, n - 1)
+            ranks = np.concatenate([ranks, extra], axis=1)
         ids = (ranks.astype(np.int64) * a + b) % int(n)
-        sparse[:, f, 0] = ids
+        sparse[:, f, :] = ids
         # per-id teacher effect: uniform with the variance of N(0, 0.49)
-        h = _mix64(ids.astype(np.uint64) ^ (salt + np.uint64(f)))
-        u = (h >> np.uint64(11)).astype(np.float64) * 2.0 ** -53
-        logits += (2.0 * u - 1.0) * 0.7 * math.sqrt(3.0)
+        x = _mix64(ids.astype(np.uint64) ^ (salt + np.uint64(f)))
+        u = (x >> np.uint64(11)).astype(np.float64) * 2.0 ** -53
+        logits += np.mean((2.0 * u - 1.0) * 0.7 * math.sqrt(3.0), axis=1)
     p = 1.0 / (1.0 + np.exp(-logits))
     label = (rng.uniform(size=n_samples) < p).astype(np.float32)
     return {"dense": dense, "sparse": sparse, "label": label}

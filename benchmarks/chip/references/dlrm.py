@@ -20,8 +20,25 @@ dense layers U(-1/sqrt(fan_in), 1/sqrt(fan_in)) with zero biases, from the
 three-way split of ``PRNGKey(seed)`` into embedding, bottom and top keys.
 
 Beside it: the MFU count, segment-wise top-k and SSU set that a priority
-save must select, the row ranges of the Emb-PS shards, and the bytes of a
-full save.
+save must select, the row ranges of the Emb-PS shards, the bytes of a full
+save, and the model operations of a training sample.
+
+The harness finds this module by a configuration's ``arch`` and calls, on
+any reference module:
+
+- ``Arch.of(cfg)``: the hashable model description from a configuration;
+- ``init_params(arch, key)``: the weights from the seed;
+- ``train(arch, seed, batches, precision, lr, eps, rows, first_rows, lap)``:
+  the reference's first steps and readings (``checks.training_gaps``);
+- ``change_norms(arch, key, params)``: per-leaf change from the initial
+  weights, of the program's parameters;
+- ``row_grad_norms(acc, params, rows)``: first-gradient row norms from a
+  row-wise Adagrad state;
+- ``segment_topk(counts, k, seg)`` and ``shard_range(n, shard, n_shards)``:
+  what a priority save selects and a failed shard covers;
+- ``full_save_bytes(arch)``: the bytes of one full save;
+- ``train_flops_per_sample(cfg)``: model operations of one training sample
+  (``train_mfu_pct``); a module without it leaves that metric silent.
 """
 from __future__ import annotations
 
@@ -275,3 +292,25 @@ def full_save_bytes(arch: Arch):
                   (F * (F - 1) // 2 + arch.emb_dim,) + arch.top_mlp):
         dense += sum(a * b + b for a, b in zip(sizes[:-1], sizes[1:]))
     return 4 * (n * arch.emb_dim + n + dense)
+
+
+# ------------------------------------------------------------ operations ----
+def mlp_flops(sizes):
+    """Forward multiply-adds of a dense stack, counted as 2 operations each."""
+    return sum(2 * a * b for a, b in zip(sizes[:-1], sizes[1:]))
+
+
+def forward_flops_per_sample(cfg):
+    """Bottom MLP, the pairwise dot-product interaction (the whole F x F
+    batched product, as it is computed) and the top MLP.  Embedding sums
+    and elementwise work are not counted."""
+    F = len(cfg["table_sizes"]) + 1
+    inter_dim = F * (F - 1) // 2 + cfg["emb_dim"]
+    bottom = mlp_flops([cfg["num_dense"]] + list(cfg["bottom_mlp"]))
+    top = mlp_flops([inter_dim] + list(cfg["top_mlp"]))
+    return bottom + top + 2 * F * F * cfg["emb_dim"]
+
+
+def train_flops_per_sample(cfg):
+    """Forward plus backward (twice the forward) model operations."""
+    return 3 * forward_flops_per_sample(cfg)

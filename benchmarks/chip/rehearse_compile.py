@@ -24,10 +24,7 @@ def compile_step(cfg, traffic, sharding):
     from repro.core import (CPRManager, Emulator, FailureInjector,
                             SystemParams)
     from repro.models import dlrm as D
-    cell_cfg = D.DLRMConfig(
-        name=cfg["name"], num_dense=cfg["num_dense"],
-        table_sizes=tuple(cfg["table_sizes"]), emb_dim=cfg["emb_dim"],
-        bottom_mlp=tuple(cfg["bottom_mlp"]), top_mlp=tuple(cfg["top_mlp"]))
+    cell_cfg = harness.program_config(cfg)
     sp = SystemParams(T_total=traffic["span_h"], **traffic["system"])
     mgr = CPRManager(traffic["mode"], sp, cfg["table_sizes"],
                      target_pls=traffic["target_pls"], r=traffic["r"],
@@ -44,8 +41,8 @@ def compile_step(cfg, traffic, sharding):
     tracker = jax.eval_shape(lambda: mgr.tracker_init(None))
     batch = {"dense": jax.ShapeDtypeStruct((B, cfg["num_dense"]),
                                            jnp.float32),
-             "sparse": jax.ShapeDtypeStruct((B, len(cfg["table_sizes"]), 1),
-                                            jnp.int32),
+             "sparse": jax.ShapeDtypeStruct(
+                 (B, len(cfg["table_sizes"]), cell_cfg.multi_hot), jnp.int32),
              "label": jax.ShapeDtypeStruct((B,), jnp.float32)}
     args = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                                        sharding=sharding),

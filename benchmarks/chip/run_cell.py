@@ -39,7 +39,8 @@ def parse(argv):
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--fault", action="append", default=[],
                     choices=("control", "half_batch", "stale_state",
-                             "alter_selection", "alter_restore"),
+                             "alter_selection", "alter_restore",
+                             "alter_image"),
                     help="break the timed path underneath, to see the check "
                          "fail (never in a benchmark run)")
     ap.add_argument("--dump", default=None,
@@ -55,7 +56,7 @@ def device_info(jax, n):
             "memory_peak_bytes": stats.get("peak_bytes_in_use")}
 
 
-def evaluate(cell, probes, limits):
+def evaluate(cell, probes, limits, image_gap=None):
     """The numbers compared with the reference -> {name: (value, limit)}."""
     import checks
     ref = cell.ref
@@ -70,7 +71,7 @@ def evaluate(cell, probes, limits):
         took[name] = round(t1 - t0, 3)
         t0 = t1
     mode = cell.traffic["mode"]
-    restores = mode in ("cpr-mfu", "cpr-ssu") and probes.failure_states
+    restores = mode != "full" and bool(probes.failure_states)
     picks, union = checks.restore_picks(cell, probes, ref) if restores \
         else (None, None)
     lap("restore_picks")
@@ -105,8 +106,11 @@ def evaluate(cell, probes, limits):
         leaves["restore_detail"] = detail
         lap("restore")
     if mode == "full":
+        nums["restore_mismatch"] = checks.full_restore_mismatch(probes)
         nums["full_save_bytes_gap"] = checks.full_save_bytes_gap(cell, probes,
                                                                  ref)
+        nums["full_image_mismatch"] = checks.full_image_mismatch(probes,
+                                                                 image_gap)
     leaves["check_s"] = took
     c1 = harness.CompileCounter.get().snapshot()
     leaves["check_compiles"] = [b - a for a, b in zip(c0, c1)]
@@ -206,8 +210,9 @@ def run(args, root=harness.ROOT, require_tpu=True, cfg=None, traffic=None,
         f"{probes.memory}")
     emu.final_params = emu.final_ostate = None
     probes.latest = None
-    emu.mgr.store = emu.mgr.writer = None
     t_check = time.perf_counter()
+    image_gap = probes.image_arrays_differ(emu.mgr.store)
+    emu.mgr.store = emu.mgr.writer = None
 
     trace = None
     if args.trace:
@@ -216,7 +221,7 @@ def run(args, root=harness.ROOT, require_tpu=True, cfg=None, traffic=None,
             trace_reduce.find_xplane(trace_dir)))
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
-    nums, leaves = evaluate(cell, probes, limits)
+    nums, leaves = evaluate(cell, probes, limits, image_gap)
     log(f"check took {time.perf_counter() - t_check:.3f} s; whole run "
         f"{time.perf_counter() - T_PROCESS:.3f} s; details {leaves}")
     correct = all(v <= lim for v, lim in nums.values())

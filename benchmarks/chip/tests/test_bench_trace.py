@@ -86,7 +86,8 @@ def test_recorded_cpu_trace(tmp_path):
 def test_train_mfu_reads_the_step_program_time():
     import harness
     import yardstick
-    cfg = {"num_dense": 13, "table_sizes": [10] * 26, "emb_dim": 16,
+    cfg = {"arch": "dlrm", "num_dense": 13, "table_sizes": [10] * 26,
+           "emb_dim": 16,
            "bottom_mlp": [512, 256, 64, 16], "top_mlp": [512, 256, 1]}
     ctx = {"trace": tr.reduce_trace(_trace()), "cfg": cfg,
            "peaks": {"bf16_flops_per_s": 197e12}, "yardstick": yardstick,

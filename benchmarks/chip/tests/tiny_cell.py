@@ -26,14 +26,18 @@ def tiny(cfg, traffic, rows=1500, batch=128):
 
 # the priority-save traffic with SSU reservoirs in place of MFU counts
 SSU = {"mode": "cpr-ssu", "tracker_backend": "host"}
+# steps of a tiny full-recovery run: its 56-h span's first save lands
+# after step 3, inside the window
+FULL_STEPS = 130
 
 
 def run_tiny(workload, seed=5, faults=(), steps=51, trace=0, seconds=1,
-             traffic_over=None):
+             traffic_over=None, cfg_over=None):
     bench = harness.load_benchmark()
     wl = harness.find(bench["workloads"], workload, "workload")
     cfg, traffic = tiny(harness.load_config(bench, wl["config"]),
                         harness.load_traffic(wl["traffic"]))
+    cfg = dict(cfg, **(cfg_over or {}))
     traffic = dict(traffic, **(traffic_over or {}))
     args = run_cell.parse(["--workload", workload, "--seed", str(seed),
                            "--seconds", str(seconds), "--trace", str(trace)])

@@ -20,25 +20,14 @@ def peaks(device_kind):
     return table[device_kind]
 
 
-def mlp_flops(sizes):
-    """Forward multiply-adds of a dense stack, counted as 2 operations each."""
-    return sum(2 * a * b for a, b in zip(sizes[:-1], sizes[1:]))
-
-
-def dlrm_forward_flops_per_sample(cfg):
-    """Bottom MLP, the pairwise dot-product interaction (the whole F x F
-    batched product, as it is computed) and the top MLP.  Embedding sums
-    and elementwise work are not counted."""
-    F = len(cfg["table_sizes"]) + 1
-    inter_dim = F * (F - 1) // 2 + cfg["emb_dim"]
-    bottom = mlp_flops([cfg["num_dense"]] + list(cfg["bottom_mlp"]))
-    top = mlp_flops([inter_dim] + list(cfg["top_mlp"]))
-    return bottom + top + 2 * F * F * cfg["emb_dim"]
-
-
 def dlrm_train_flops_per_sample(cfg):
-    """Forward plus backward (twice the forward) model operations."""
-    return 3 * dlrm_forward_flops_per_sample(cfg)
+    """Model operations of one training sample (forward and backward), as
+    the reference module of ``cfg["arch"]`` counts them
+    (``train_flops_per_sample``); None where it has no count."""
+    import harness
+    count = getattr(harness.reference(cfg["arch"]), "train_flops_per_sample",
+                    None)
+    return None if count is None else count(cfg)
 
 
 def segmented_k(n, rn, seg_size=512):
