@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 
+from repro.models.dlrm import DLRM_DCNV2 as _DCNV2_BASE
 from repro.models.dlrm import DLRM_KAGGLE as _KAGGLE_BASE
 from repro.models.dlrm import DLRM_TERABYTE as _TERABYTE_BASE
 
@@ -28,10 +29,25 @@ CRITEO_TERABYTE_TABLE_SIZES = (
     39664984, 585935, 12972, 108, 36,
 )
 
+# MLPerf DLRM-DCNv2's Criteo 1TB multi-hot cardinalities (capped at 40M) and
+# lookups per table (214 a sample).
+CRITEO_1TB_MLPERF_TABLE_SIZES = (
+    40000000, 39060, 17295, 7424, 20265, 3, 7122, 1543, 63, 40000000,
+    3067956, 405282, 10, 2209, 11938, 155, 4, 976, 14, 40000000, 40000000,
+    40000000, 590152, 12973, 108, 36,
+)
+MLPERF_DCNV2_HOTNESS = (
+    3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100, 27,
+    10, 3, 1, 1,
+)
+
 DLRM_KAGGLE = dataclasses.replace(_KAGGLE_BASE,
                                   table_sizes=CRITEO_KAGGLE_TABLE_SIZES)
 DLRM_TERABYTE = dataclasses.replace(_TERABYTE_BASE,
                                     table_sizes=CRITEO_TERABYTE_TABLE_SIZES)
+DLRM_DCNV2 = dataclasses.replace(_DCNV2_BASE,
+                                 table_sizes=CRITEO_1TB_MLPERF_TABLE_SIZES,
+                                 multi_hot=MLPERF_DCNV2_HOTNESS)
 
 
 def scaled(cfg, max_rows: int = 100_000):

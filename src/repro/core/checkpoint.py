@@ -347,9 +347,15 @@ class CheckpointStore:
 
     def save_rows(self, table: int, rows: np.ndarray, values: np.ndarray,
                   acc_values: np.ndarray, step: int = 0):
-        """Partial (priority) save of selected rows of one table."""
-        rows, values, acc_values = self._filter_rows(table, rows, values,
-                                                     acc_values)
+        """Partial (priority) save of selected rows of one table.  The
+        image takes the rows before this returns, so the arrays are read in
+        place: no copy is made unless some row id is out of range."""
+        rows = np.asarray(rows)
+        if rows.size and (rows.min() < 0 or
+                          rows.max() >= self.spec.table_sizes[table]):
+            rows, values, acc_values = self._filter_rows(table, rows, values,
+                                                         acc_values)
+        values, acc_values = np.asarray(values), np.asarray(acc_values)
         if rows.size == 0:
             return 0
         self.image_tables[table][rows] = values

@@ -94,14 +94,13 @@ class Emulator:
                 updates, ostate = opt.update(grads, ostate, params)
                 params = apply_updates(params, updates)
             with jax.named_scope("tracker"):
+                def ids(t):
+                    return D.table_ids(cfg, batch["sparse"], t)
                 if mode == "cpr-mfu":
-                    tracker = {t: trk.mfu_update(tracker[t],
-                                                 batch["sparse"][:, t, :])
+                    tracker = {t: trk.mfu_update(tracker[t], ids(t))
                                for t in big}
                 elif mode == "cpr-ssu":
-                    tracker = {t: trk.ssu_update(tracker[t],
-                                                 batch["sparse"][:, t, :],
-                                                 period,
+                    tracker = {t: trk.ssu_update(tracker[t], ids(t), period,
                                                  backend=mgr.tracker_backend)
                                for t in big}
             return params, ostate, tracker, loss
@@ -116,7 +115,7 @@ class Emulator:
         ostate = opt.init(params)
         if resume_from:
             # disk-mode full recovery: embedding shards + optimizer rows +
-            # the trainer replica (bottom/top MLPs) all come back from the
+            # the trainer replica (every dense leaf) all come back from the
             # last consistent checkpoint cycle, whichever store layout
             # (flat or per-shard fleet) wrote it.  load_latest_auto resolves
             # the run-versioned CURRENT pointer first, so a prior run that
@@ -125,8 +124,7 @@ class Emulator:
             loaded = load_latest_auto(
                 resume_from, [np.asarray(t) for t in params["tables"]],
                 [np.asarray(a) for a in ostate["acc"]["tables"]], mgr.spec,
-                trainer_state={"bottom": params["bottom"],
-                               "top": params["top"]})
+                trainer_state=D.trainer_state(params))
             r_t, r_a, trainer = loaded.restore_all()
             params = {**params, "tables": [jnp.asarray(x) for x in r_t]}
             if trainer is not None:
@@ -137,7 +135,7 @@ class Emulator:
                               "tables": [jnp.asarray(x) for x in r_a]}}
         tracker = mgr.tracker_init(params["tables"])
         mgr.attach_store(params["tables"], ostate["acc"]["tables"],
-                         {"bottom": params["bottom"], "top": params["top"]})
+                         D.trainer_state(params))
 
         (tr0, tr1), (ev0, ev1) = self.ds.eval_split(self.eval_frac)
         n_train = tr1 - tr0
@@ -160,7 +158,7 @@ class Emulator:
             for t_ev in mgr.due_saves(t):
                 tracker = mgr.run_save(
                     t_ev, params["tables"], ostate["acc"]["tables"], tracker,
-                    {"bottom": params["bottom"], "top": params["top"]}, step=i)
+                    D.trainer_state(params), step=i)
             for ev in self.injector.between(t_prev, t):
                 fid = mgr.n_failures
                 with span("cpr.restore", failure=fid,

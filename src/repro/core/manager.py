@@ -68,6 +68,21 @@ class OverheadLedger:
         return d
 
 
+def _order(a: np.ndarray) -> str:
+    return "C" if a.flags.c_contiguous else "F"
+
+
+def _take_rows(src: np.ndarray, rows: np.ndarray, out: np.ndarray):
+    """``src[rows]`` written into ``out``, which has ``src``'s memory order.
+    ``np.take`` reads a C-contiguous source in place and first copies any
+    other whole, so a column-major source is read through its transpose,
+    one contiguous column at a time.  The TPU keeps a narrow table (n, 16)
+    column-major, and its host copy comes back in that order."""
+    if src.flags.c_contiguous:
+        return np.take(src, rows, axis=0, out=out, mode="clip")
+    return np.take(src.T, rows, axis=-1, out=out.T, mode="clip").T
+
+
 class CPRManager:
     def __init__(self, mode: str, sys_params: oh.SystemParams,
                  table_sizes, target_pls: float = 0.1, r: float = 0.125,
@@ -202,6 +217,9 @@ class CPRManager:
         # and Emulator.run its restores' (report()["transfers"])
         self.counters = {"save_fetch_bytes": 0, "restore_fetch_bytes": 0,
                          "restore_put_bytes": 0}
+        # per big table: (shapes, dtypes and orders, host buffers) a save
+        # gathers its picked rows into, kept from save to save (_gather)
+        self._gather_bufs: Dict[int, tuple] = {}
         self.last_cycle_time = np.zeros(sys_params.N_emb)  # per-shard
         self._next_save_idx = 1       # multiples of sub-interval
         self.store = None             # CheckpointStore | ShardedCheckpointWriter
@@ -406,7 +424,7 @@ class CPRManager:
                         t, n, rn, tables, tracker_state, pending_indices)
                 if rows.size:
                     with span("cpr.save.gather", save=sid, table=t):
-                        vals, acc_vals = tab[rows], acc[rows]
+                        vals, acc_vals = self._gather(t, tab, acc, rows)
                     with span("cpr.save.store", save=sid, table=t):
                         nbytes += saver.save_rows(t, rows, vals, acc_vals,
                                                   step=step)
@@ -480,6 +498,25 @@ class CPRManager:
                                          "shards": readmitted})
             self._maybe_tune_parity(tracker_state, t_event)
         return tracker_state, nbytes
+
+    def _gather(self, t, tab, acc, rows):
+        """Rows ``rows`` of big table ``t`` and of its accumulator, written
+        into host buffers that this manager keeps from save to save.  A fresh
+        array each save is fresh memory each save, and the time the host
+        takes to first touch fresh pages varies (on the TPU host, from run
+        to run and after each failure's large copies); memory written at
+        every save stays resident.  The ids are in range (``_select`` drops
+        padding picks), so ``mode="clip"`` only skips numpy's bounds-checked
+        copy.  The views returned are overwritten by the next save of ``t``:
+        every saver copies or applies them before ``save_rows`` returns."""
+        want = [((rows.size,) + x.shape[1:], x.dtype, _order(x))
+                for x in (tab, acc)]
+        kept = self._gather_bufs.get(t)
+        if kept is None or kept[0] != want:
+            kept = (want, [np.empty(s, d, order=o) for s, d, o in want])
+            self._gather_bufs[t] = kept
+        return tuple(_take_rows(x, rows, b)
+                     for x, b in zip((tab, acc), kept[1]))
 
     def _select(self, t, n, rn, tables, tracker_state, pending_indices):
         """The tracker's pick of big table ``t`` -> (row ids on the host,

@@ -171,10 +171,13 @@ def test_full_recovery_save_counts_every_array(setup):
         sum(tab_b) + sum(acc_b) + trainer)
 
 
-def test_step_hlo_carries_the_scopes(setup):
-    """The compiled step's op names carry forward, its transpose (the
-    backward pass), optimizer and tracker."""
-    cfg, ds = setup
+# the model's own scopes inside ``forward``, forward and backward
+MODEL_SCOPES = tuple(f"jit(step)/{d}/{s}/"
+                     for d in ("jvp(forward)", "transpose(jvp(forward))")
+                     for s in ("embedding", "interaction"))
+
+
+def _step_text(cfg, ds):
     p = SystemParams()
     mgr = CPRManager("cpr-mfu", p, cfg.table_sizes)
     emu = Emulator(cfg, ds, mgr, FailureInjector(0, 0.25, p.N_emb, p.T_total))
@@ -182,12 +185,33 @@ def test_step_hlo_carries_the_scopes(setup):
     from repro.models import dlrm as D
     params = D.init_dlrm(cfg, jax.random.PRNGKey(0))
     batch = next(iter(ds.batches(64, 0, 64)))
-    text = step.lower(params, opt.init(params),
+    return step.lower(params, opt.init(params),
                       mgr.tracker_init(params["tables"]),
                       batch).compile().as_text()
+
+
+def test_step_hlo_carries_the_scopes(setup):
+    """The compiled step's op names carry forward, its transpose (the
+    backward pass), optimizer and tracker, and inside forward the embedding
+    bags and the (dot) interaction."""
+    text = _step_text(*setup)
     for path in ("jit(step)/jvp(forward)/",
                  "jit(step)/transpose(jvp(forward))/",
-                 "jit(step)/optimizer/", "jit(step)/tracker/"):
+                 "jit(step)/optimizer/", "jit(step)/tracker/") + MODEL_SCOPES:
+        assert f'op_name="{path}' in text, path
+
+
+def test_dcn_step_hlo_carries_the_model_scopes():
+    """The same scopes around DLRM-DCNv2's multi-hot bags and cross layers."""
+    from repro.models.dlrm import DLRMConfig
+    cfg = DLRMConfig(name="dcn-small", num_dense=13,
+                     table_sizes=(300, 40, 7, 250), emb_dim=8,
+                     bottom_mlp=(16, 8), top_mlp=(32, 1), multi_hot=2,
+                     interaction="dcn", dcn_num_layers=2, dcn_low_rank_dim=4)
+    ds = ClickLogDataset(cfg.table_sizes, num_samples=128, multi_hot=2,
+                         seed=3)
+    text = _step_text(cfg, ds)
+    for path in MODEL_SCOPES:
         assert f'op_name="{path}' in text, path
 
 
